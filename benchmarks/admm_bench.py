@@ -23,6 +23,7 @@ from repro.core.pruning import (
     convergence_metrics,
     hard_prune,
 )
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def run_admm(sparsity: float, steps: int = 300, d: int = 64):
@@ -64,6 +65,7 @@ def run_admm(sparsity: float, steps: int = 300, d: int = 64):
 
 
 def main():
+    enable_compile_cache()
     print("admm,sparsity,primal_residual,pruned_loss,dense_loss,ratio")
     for sp in (0.25, 0.5, 0.75):
         res, lp, ld = run_admm(sp)

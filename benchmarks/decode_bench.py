@@ -42,6 +42,7 @@ from repro.kernels import ops as kops
 from repro.models.transformer import forward, init_lm
 from repro.models.transformer_graph import build_decoder_graph, decoder_cache_spec
 from repro.serving import AsyncPlanServer, PagedKVCache
+from repro.utils.compile_cache import enable_compile_cache
 
 RESULTS_DIR = os.path.join(os.path.dirname(__file__), "..", "results")
 
@@ -193,6 +194,7 @@ def bench_decode(smoke: bool = False, out_path: str | None = None) -> dict:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true", help="tiny traffic (CI, no TPU)")
     bench_decode(smoke=ap.parse_args().smoke)

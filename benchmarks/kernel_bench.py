@@ -56,6 +56,7 @@ from repro.core.pruning import Block, Column, project
 from repro.core.sparse import CSR, ColumnCompact, PBCSR, dense_nbytes
 from repro.kernels import bsr_matmul, matmul, ref
 from repro.kernels import ops as kops
+from repro.utils.compile_cache import enable_compile_cache
 
 K, N, M = 2048, 2048, 256
 
@@ -545,6 +546,7 @@ def main(smoke: bool = False):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true", help="tiny shapes (CI, no TPU)")
     ap.add_argument("--repeat", type=int, default=None,

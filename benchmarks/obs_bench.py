@@ -43,6 +43,7 @@ import numpy as np
 from repro.core.graph import compile_plan
 from repro.models.cnn import APPS
 from repro.obs import metrics, profile_plan, trace
+from repro.utils.compile_cache import enable_compile_cache
 
 RESULTS_DIR = os.path.join(os.path.dirname(__file__), "..", "results")
 
@@ -203,6 +204,7 @@ def bench_obs(smoke: bool = False, out_path: str | None = None,
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true", help="tiny shapes (CI)")
     ap.add_argument("--attempts", type=int, default=5,

@@ -14,14 +14,14 @@ Usage:  python -m benchmarks.perf_iterations [cellA|cellB|cellC ...]
 Writes results/perf/<cell>__<variant>.json and prints the iteration log.
 """
 
-import os
-os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=512")
-
 import dataclasses
 import json
+import os
 import sys
 
 from jax.sharding import PartitionSpec as P
+
+from repro.utils.compile_cache import enable_compile_cache
 
 RESULTS = os.path.join(os.path.dirname(__file__), "..", "results", "perf")
 
@@ -145,6 +145,9 @@ def cell_c():
 
 
 def main():
+    # 512 host devices, set before the first backend init (never at import)
+    os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=512")
+    enable_compile_cache()
     which = sys.argv[1:] or ["cellA", "cellB", "cellC"]
     if "cellA" in which:
         cell_a()

@@ -44,6 +44,7 @@ from repro.core.graph import compile_plan
 from repro.models.cnn import APPS
 from repro.robustness import FaultPlan, FaultRule, GuardConfig
 from repro.serving import AsyncPlanServer, submit_with_retry
+from repro.utils.compile_cache import enable_compile_cache
 
 RESULTS_DIR = os.path.join(os.path.dirname(__file__), "..", "results")
 
@@ -259,6 +260,7 @@ def bench_robustness(
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true", help="tiny shapes (CI, no TPU)")
     ap.add_argument(

@@ -19,6 +19,8 @@ import json
 import os
 import sys
 
+from repro.utils.compile_cache import enable_compile_cache
+
 
 def _roofline_summary() -> None:
     base = os.path.join(os.path.dirname(__file__), "..", "results", "dryrun")
@@ -46,6 +48,7 @@ def _roofline_summary() -> None:
 
 
 def main() -> None:
+    enable_compile_cache()
     sections = sys.argv[1:] or ["table1", "kernels", "fusion", "admm", "roofline"]
     if "table1" in sections:
         from . import table1_apps

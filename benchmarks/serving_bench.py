@@ -44,6 +44,7 @@ from repro.core.graph import compile_plan, optimize
 from repro.kernels import ops as kops
 from repro.models.cnn import APPS, app_masks
 from repro.serving import AsyncPlanServer, QueueFullError
+from repro.utils.compile_cache import enable_compile_cache
 
 RESULTS_DIR = os.path.join(os.path.dirname(__file__), "..", "results")
 
@@ -375,6 +376,7 @@ def bench_serving(smoke: bool = False, out_path: str | None = None) -> dict:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true", help="tiny shapes (CI, no TPU)")
     bench_serving(smoke=ap.parse_args().smoke)

@@ -21,7 +21,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.graph import compile_plan, lower, optimize
-from repro.utils.jax_compat import cost_analysis
 from repro.core.graph.ir import Graph
 from repro.models.cnn import (  # noqa: F401  (re-exported for tests/scripts)
     APPS,
@@ -31,6 +30,7 @@ from repro.models.cnn import (  # noqa: F401  (re-exported for tests/scripts)
     _pattern_mask,
     app_masks,
 )
+from repro.utils.compile_cache import enable_compile_cache
 
 INPUT_SHAPES = {
     "style_transfer": (1, 3, 128, 128),
@@ -57,7 +57,7 @@ def count_graph_flops(g: Graph, x_shape: Tuple[int, ...]) -> float:
     x = jax.ShapeDtypeStruct(x_shape, jnp.float32)
     params = jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), g.params)
     lowered = jax.jit(fn).lower(params, x)
-    return float(cost_analysis(lowered.compile()).get("flops", 0.0))
+    return float(lowered.compile().cost_analysis().get("flops", 0.0))
 
 
 def graph_param_bytes(g: Graph) -> int:
@@ -144,6 +144,7 @@ def main(smoke: bool = False) -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true", help="tiny shapes (CI, no TPU)")
     main(smoke=ap.parse_args().smoke)

@@ -53,6 +53,7 @@ __all__ = [
     "handlers_for",
     "guard_fallback_counts",
     "reset_guard_fallbacks",
+    "jnp_route_counts",
     "Runtime",
     "Step",
     "ExecutionPlan",
@@ -114,6 +115,24 @@ def guard_fallback_counts() -> Dict[str, int]:
 
 def reset_guard_fallbacks() -> None:
     _metrics.registry().reset(_GUARD_METRIC)
+
+
+#: work a kernel-backend handler hands to jnp instead of a Pallas kernel,
+#: by op and reason (counted at trace time under jit): a ``fused_elementwise``
+#: node the tiled kernel cannot express, or a GEMM/conv epilogue that runs
+#: as a jnp tail after the kernel -- the siblings of conv_fallback_total
+_JNP_ROUTE_METRIC = "kernel_jnp_route_total"
+
+
+def jnp_route_counts() -> Dict[str, int]:
+    """Kernel-backend jnp routes keyed ``"op/reason"`` -- a view over the
+    ``kernel_jnp_route_total`` registry family."""
+    counts = _metrics.registry().label_counts(_JNP_ROUTE_METRIC, "op", "reason")
+    return {k: int(v) for k, v in counts.items()}
+
+
+def _count_jnp_route(op: str, reason: str) -> None:
+    _metrics.registry().counter(_JNP_ROUTE_METRIC, op=op, reason=reason).inc()
 
 
 def _node_scheme(n: Node) -> str:
@@ -220,12 +239,13 @@ def _apply_epilogue(y, epilogue, xs, p):
     return kref.apply_steps_ref(y, steps, sides, norms)
 
 
-def _kernel_epilogue(epilogue, xs, out_shape):
+def _kernel_epilogue(epilogue, xs, out_shape, op):
     """Translate an epilogue into the Pallas matmul's kernel-local form:
     ``(steps, sides)`` with slots renumbered into ``sides``.  Returns
     ``(None, None)`` when the program cannot run tiled in-kernel (norm steps
     need whole rows; mismatched side shapes cannot be streamed per-tile) --
-    callers then fall back to :func:`_apply_epilogue` after the GEMM."""
+    callers then fall back to :func:`_apply_epilogue` after the GEMM, and
+    the jnp tail is counted under ``op`` in ``kernel_jnp_route_total``."""
     steps, sides = [], []
     for step in epilogue:
         kind = step[0]
@@ -234,10 +254,12 @@ def _kernel_epilogue(epilogue, xs, out_shape):
         elif kind in ("add", "mul"):
             s = xs[step[1]]
             if tuple(s.shape) != tuple(out_shape):
+                _count_jnp_route(op, "epilogue_broadcast_side")
                 return None, None
             sides.append(s)
             steps.append((kind, len(sides) - 1))
         else:  # norm_layer / norm_instance: need full rows / spatial planes
+            _count_jnp_route(op, f"epilogue_{kind}")
             return None, None
     return tuple(steps), tuple(sides)
 
@@ -251,7 +273,7 @@ def _kernel_epilogue(epilogue, xs, out_shape):
 def _linear_kernel(p, xs, a, rt):
     epi = a.get("epilogue") or ()
     out_shape = (*xs[0].shape[:-1], p["w"].shape[1])
-    steps, sides = _kernel_epilogue(epi, xs, out_shape)
+    steps, sides = _kernel_epilogue(epi, xs, out_shape, "linear")
     if steps is None:  # not tile-fusable: run the GEMM, apply epilogue in jnp
         y = kops.matmul(
             xs[0], p["w"], p.get("b"), activation=a.get("activation"),
@@ -277,7 +299,7 @@ def _sparse_linear_kernel(p, xs, a, rt):
     if fmt in ("colcompact", "channelcompact"):
         values = p["values"]
         out_shape = (*xs[0].shape[:-1], values.shape[1])
-        steps, sides = _kernel_epilogue(epi, xs, out_shape)
+        steps, sides = _kernel_epilogue(epi, xs, out_shape, "sparse_linear")
         kw = dict(activation=a.get("activation"), interpret=rt.interpret)
         if steps is not None:
             kw.update(epilogue=steps, epilogue_sides=sides)
@@ -292,7 +314,7 @@ def _sparse_linear_kernel(p, xs, a, rt):
         # norm steps / broadcast sides fall back to the jnp tail
         nb, _, _, bn = p["values"].shape
         out_shape = (*xs[0].shape[:-1], nb * bn)
-        steps, sides = _kernel_epilogue(epi, xs, out_shape)
+        steps, sides = _kernel_epilogue(epi, xs, out_shape, "sparse_linear")
         kw = dict(
             activation=a.get("activation"), bands=a.get("bands"),
             interpret=rt.interpret,
@@ -347,7 +369,7 @@ def _qlinear_quant(p, xs, a, rt):
         x = jnp.take(x, p["kept"], axis=-1)
     epi = a.get("epilogue") or ()
     out_shape = (*xs[0].shape[:-1], p["values"].shape[1])
-    steps, sides = _kernel_epilogue(epi, xs, out_shape)
+    steps, sides = _kernel_epilogue(epi, xs, out_shape, "qlinear")
     kw = dict(
         x_scale=a.get("x_scale"), activation=a.get("activation"),
         interpret=rt.interpret, _format=a.get("format", "dense"),
@@ -401,7 +423,7 @@ def _conv2d_kernel(p, xs, a, rt):
     the surviving input channels.  Unsupported configs (groups, dilation,
     VMEM overflow) auto-fall back to lax.conv inside the wrapper."""
     epi = a.get("epilogue") or ()
-    steps, sides = _kernel_epilogue(epi, xs, _conv_out_shape(p, xs, a))
+    steps, sides = _kernel_epilogue(epi, xs, _conv_out_shape(p, xs, a), "conv2d")
     kw = _conv_call_kwargs(p, a, rt)
     if steps is not None:
         kw.update(epilogue=steps, epilogue_sides=sides)
@@ -432,7 +454,9 @@ def _qconv2d_quant(p, xs, a, rt):
     dequant-to-f32-then-lax.conv path, so the f32 weight copy never
     materializes in HBM."""
     epi = a.get("epilogue") or ()
-    steps, sides = _kernel_epilogue(epi, xs, _conv_out_shape(p, xs, a, "values"))
+    steps, sides = _kernel_epilogue(
+        epi, xs, _conv_out_shape(p, xs, a, "values"), "qconv2d"
+    )
     kw = _conv_call_kwargs(p, a, rt)
     kw.update(w_scale=p["w_scale"], x_scale=a.get("x_scale"))
     if steps is not None:
@@ -507,19 +531,36 @@ def _fused_elementwise(p, xs, a, rt):
     return kref.apply_steps_ref(xs[0], steps, sides, norms)
 
 
+def _ew_reference_reason(x, xs, steps, norms) -> Optional[str]:
+    """Why the tiled kernel cannot express a fused_elementwise node, or
+    None when it can."""
+    if x.ndim < 2:
+        return "rank"
+    if any(s.shape != x.shape for s in xs[1:]):
+        return "broadcast_side"
+    for st in steps:  # the kernel's step vocabulary
+        if st[0] not in ("activation", "add", "mul", "norm"):
+            return st[0]  # norm_instance needs whole spatial planes
+    if any(
+        s is None or s.ndim != 1 or s.shape[-1] != x.shape[-1]
+        for pair in norms for s in pair
+    ):
+        return "norm_params"
+    return None
+
+
 @register_op("fused_elementwise", backends=("kernel",))
 def _fused_elementwise_kernel(p, xs, a, rt):
     """One VMEM-resident Pallas pass over the whole step program: one HBM
     read + write total.  Falls back to the jnp interpreter when the tiled
-    kernel cannot express the node (broadcast sides, rank < 2, non-vector
-    norm params)."""
+    kernel cannot express the node (rank < 2, broadcast sides, a step
+    outside its vocabulary such as instance norm, non-vector norm params),
+    counted per reason in ``kernel_jnp_route_total``."""
     x = xs[0]
-    if x.ndim < 2 or any(s.shape != x.shape for s in xs[1:]):
-        return _fused_elementwise(p, xs, a, rt)
     steps, sides, norms = _steps_local(a["steps"], xs, p)
-    if any(st[0] == "norm_instance" for st in steps) or any(
-        s.ndim != 1 or s.shape[-1] != x.shape[-1] for pair in norms for s in pair
-    ):
+    reason = _ew_reference_reason(x, xs, steps, norms)
+    if reason is not None:
+        _count_jnp_route("fused_elementwise", reason)
         return _fused_elementwise(p, xs, a, rt)
     return kops.fused_elementwise(x, sides, tuple(steps), norms, interpret=rt.interpret)
 

@@ -31,8 +31,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .pallas_compat import tpu_compiler_params as _tpu_compiler_params
-
 from .dense_matmul import _ACTIVATIONS, apply_epilogue_steps, validate_epilogue
 
 __all__ = ["bsr_matmul_kernel", "bsr_matmul"]
@@ -170,7 +168,7 @@ def bsr_matmul(
         kern,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
-        compiler_params=_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,

@@ -72,9 +72,9 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .dense_matmul import _ACTIVATIONS, apply_epilogue_steps, validate_epilogue
-from .pallas_compat import tpu_compiler_params as _tpu_compiler_params
 
 __all__ = [
+    "VMEM_LIMIT_BYTES",
     "conv2d_gemm_kernel",
     "conv2d_gemm",
     "conv_out_hw",
@@ -82,6 +82,10 @@ __all__ = [
     "conv_padding_token",
     "conv_vmem_workspace",
 ]
+
+
+#: scoped-VMEM budget the conv kernel compiles with (``vmem_limit_bytes``)
+VMEM_LIMIT_BYTES = 96 * 2**20
 
 
 def _explicit_pads(padding) -> Tuple[Tuple[int, int], Tuple[int, int]]:
@@ -127,6 +131,14 @@ def conv_padding_token(padding) -> str:
     return f"+p{a}.{b}.{c}.{d}"
 
 
+def _vmem_tile(rows: int, cols: int, itemsize: int) -> int:
+    """Bytes of a ``[rows, cols]`` array in Mosaic's VMEM layout: the minor
+    dim pads to 128 lanes, the second-minor to the dtype's sublane tiling
+    (8 rows of 32 bits, so 32 rows of int8)."""
+    sub = 8 * 4 // itemsize
+    return -(-rows // sub) * sub * (-(-cols // 128) * 128) * itemsize
+
+
 def conv_vmem_workspace(
     c: int,
     h: int,
@@ -140,28 +152,32 @@ def conv_vmem_workspace(
     block_c: int = 0,
     x_itemsize: int = 4,
     w_itemsize: int = 4,
+    n_sides: int = 0,
 ) -> dict:
-    """Per-grid-step VMEM working set of the implicit-GEMM kernel: the
-    resident image slab, one filter tile, the in-flight im2col patch tile,
-    and the f32 accumulator/output tile.  ``block_c == 0`` means the legacy
-    resident-image path (all ``C`` channels in VMEM at once); ``block_c > 0``
-    is the tiled-K contraction, where only a ``block_c``-channel slab is
-    resident per grid step (plus the cross-step accumulator scratch).
-    Shared by the ``ops.conv2d`` fallback guard and
-    :meth:`ExecutionPlan.memory_estimate` (the im2col scratch never touches
-    HBM, so it must be accounted as VMEM-side peak working memory, not
-    activation bytes)."""
+    """Per-grid-step VMEM working set of the implicit-GEMM kernel in
+    Mosaic's tiled layout: the image slab and filter tile (double-buffered
+    by the grid pipeline), the output and epilogue-side tiles (likewise),
+    the in-flight im2col patch tile, and the f32 accumulator.  ``block_c ==
+    0`` means the resident-image path (all ``C`` channels in VMEM at once);
+    ``block_c > 0`` is the tiled-K contraction, where only a
+    ``block_c``-channel slab is resident per grid step (plus the cross-step
+    accumulator scratch).  Shared by the ``ops.conv2d`` fallback guard,
+    which admits a configuration only when ``total`` fits
+    :data:`VMEM_LIMIT_BYTES`, and :meth:`ExecutionPlan.memory_estimate`
+    (the im2col scratch never touches HBM, so it is VMEM-side working
+    memory, not activation bytes)."""
     oh, ow = conv_out_hw(h, w, kh, kw, stride, padding)
     ohp = -(-max(oh, 1) // block_h) * block_h
     hp = (ohp - 1) * stride + kh
     wp = (max(ow, 1) - 1) * stride + kw
     bm = block_h * max(ow, 1)
     c_eff = min(c, block_c) if block_c else c
-    image = hp * wp * c_eff * x_itemsize
-    weights = kh * kw * c_eff * block_o * w_itemsize
-    patch = bm * c_eff * x_itemsize  # one (ki, kj) im2col tile resident at a time
-    acc = bm * block_o * 4
-    out = bm * block_o * 4
+    image = 2 * hp * _vmem_tile(wp, c_eff, x_itemsize)
+    weights = 2 * kh * kw * _vmem_tile(c_eff, block_o, w_itemsize)
+    # one tap's strided load and its [bm, C] GEMM view
+    patch = 2 * block_h * _vmem_tile(max(ow, 1), c_eff, x_itemsize)
+    acc = (2 if block_c else 1) * _vmem_tile(bm, block_o, 4)
+    out = 2 * (1 + n_sides) * _vmem_tile(bm, block_o, 4)
     return {
         "image": int(image),
         "weights": int(weights),
@@ -202,13 +218,15 @@ def conv2d_gemm_kernel(
     bm = block_h * out_w
     a8 = jnp.issubdtype(x_ref.dtype, jnp.integer)
     acc = jnp.zeros((bm, o_ref.shape[1]), jnp.int32 if a8 else jnp.float32)
-    row_span = stride * (block_h - 1) + 1
-    col_span = stride * (out_w - 1) + 1
     for ki in range(kh):
         for kj in range(kw):
-            rows = x_ref[0, pl.ds(i * (block_h * stride) + ki, row_span), pl.ds(kj, col_span), :]
-            if stride > 1:
-                rows = rows[::stride, ::stride, :]
+            # strided ref loads: Mosaic has no strided slice of a loaded value
+            rows = x_ref[
+                0,
+                pl.ds(i * (block_h * stride) + ki, block_h, stride=stride),
+                pl.ds(kj, out_w, stride=stride),
+                :,
+            ]
             patch = rows.reshape(bm, c)  # the im2col tile -- VMEM only
             wk = w_ref[ki * kw + kj]  # [C, block_o]
             if a8:
@@ -384,8 +402,8 @@ def conv2d_gemm(
         out_specs=out_tile,
         out_shape=jax.ShapeDtypeStruct((m, op), out_dtype),
         scratch_shapes=scratch,
-        compiler_params=_tpu_compiler_params(
-            dimension_semantics=semantics
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=semantics, vmem_limit_bytes=VMEM_LIMIT_BYTES,
         ),
         interpret=interpret,
     )(*args)

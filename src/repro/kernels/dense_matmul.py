@@ -37,8 +37,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .pallas_compat import tpu_compiler_params as _tpu_compiler_params
-
 __all__ = [
     "dense_matmul_kernel",
     "dense_matmul_pipelined_kernel",
@@ -119,8 +117,8 @@ def dense_matmul_kernel(
 
 
 def dense_matmul_pipelined_kernel(
-    x_hbm,  # [bm, K] row panel, left in HBM (memory_space=ANY)
-    w_hbm,  # [K, bn] column panel, left in HBM (memory_space=ANY)
+    x_hbm,  # [M, K] whole operand, left in HBM (memory_space=ANY)
+    w_hbm,  # [K, N] whole operand, left in HBM (memory_space=ANY)
     b_ref,
     side_refs,
     o_ref,
@@ -140,17 +138,18 @@ def dense_matmul_pipelined_kernel(
     before slab ``s``'s is awaited, so the copy of the next operands overlaps
     the MXU work on the current ones; the accumulator is the loop carry."""
 
+    bm, bn = o_ref.shape
+    rows = pl.ds(pl.multiple_of(pl.program_id(0) * bm, bm), bm)
+    cols = pl.ds(pl.multiple_of(pl.program_id(1) * bn, bn), bn)
+
     def copies(slot, step):
+        ks = pl.ds(pl.multiple_of(step * block_k, block_k), block_k)
         return (
             pltpu.make_async_copy(
-                x_hbm.at[:, pl.ds(step * block_k, block_k)],
-                x_slots.at[slot],
-                sem.at[slot, 0],
+                x_hbm.at[rows, ks], x_slots.at[slot], sem.at[slot, 0]
             ),
             pltpu.make_async_copy(
-                w_hbm.at[pl.ds(step * block_k, block_k), :],
-                w_slots.at[slot],
-                sem.at[slot, 1],
+                w_hbm.at[ks, cols], w_slots.at[slot], sem.at[slot, 1]
             ),
         )
 
@@ -228,10 +227,9 @@ def dense_matmul(
     pipelined = pipeline >= 2
     if pipelined:
         grid = (m // block_m, n // block_n)
-        any_space = pltpu.TPUMemorySpace.ANY
         in_specs = [
-            pl.BlockSpec((block_m, k), lambda i, j: (i, 0), memory_space=any_space),
-            pl.BlockSpec((k, block_n), lambda i, j: (0, j), memory_space=any_space),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ]
         bias_tile = pl.BlockSpec((1, block_n), lambda i, j: (0, j))
         out_tile = pl.BlockSpec((block_m, block_n), lambda i, j: (i, j))
@@ -301,7 +299,7 @@ def dense_matmul(
         out_specs=out_tile,
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=scratch,
-        compiler_params=_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=semantics
         ),
         interpret=interpret,

@@ -21,8 +21,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .pallas_compat import tpu_compiler_params as _tpu_compiler_params
-
 __all__ = ["flash_attention_kernel", "flash_attention"]
 
 NEG_INF = -1e30
@@ -52,7 +50,7 @@ def flash_attention_kernel(
     if len_ref is not None:
         # valid-prefix mask: only KV slots < length attend (paged decode where
         # Skv is padded out to a page multiple past the live cache entries)
-        s = jnp.where(cols < len_ref[0, 0], s, NEG_INF)
+        s = jnp.where(cols < len_ref[pl.program_id(0)], s, NEG_INF)
     m_prev = m_ref[...]
     m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
     alpha = jnp.exp(m_prev - m_new)
@@ -69,7 +67,7 @@ def flash_attention_kernel(
 
 
 def _flash_attention_kernel_len(
-    q_ref, k_ref, v_ref, len_ref, o_ref, m_ref, l_ref, acc_ref,
+    len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
     *, scale, causal, bq, bk,
 ):
     flash_attention_kernel(
@@ -109,7 +107,7 @@ def flash_attention(
         pltpu.VMEM((block_q, 1), jnp.float32),
         pltpu.VMEM((block_q, d), jnp.float32),
     ]
-    params = _tpu_compiler_params(
+    params = pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "arbitrary")
     )
     if kv_lengths is None:
@@ -127,26 +125,25 @@ def flash_attention(
             interpret=interpret,
         )(qf, kf, vf)
     else:
-        # lengths ride as a [bh, 1] int32 scalar block in SMEM (2D: TPU
-        # scalars must be at least rank 2 -- see pallas guide)
-        lens = jnp.repeat(
-            jnp.asarray(kv_lengths, jnp.int32).reshape(b), h
-        ).reshape(bh, 1)
+        # per-(batch, head) lengths are scalar-prefetched into SMEM: a
+        # [bh] int32 vector indexed by the grid's first coordinate
+        lens = jnp.repeat(jnp.asarray(kv_lengths, jnp.int32).reshape(b), h)
+        q_spec = pl.BlockSpec((1, block_q, d), lambda g, i, j, _: (g, i, 0))
+        kv_spec = pl.BlockSpec((1, block_k, d), lambda g, i, j, _: (g, j, 0))
         out = pl.pallas_call(
             functools.partial(
                 _flash_attention_kernel_len,
                 scale=scale, causal=causal, bq=block_q, bk=block_k,
             ),
-            grid=grid,
-            in_specs=[
-                q_spec, kv_spec, kv_spec,
-                pl.BlockSpec((1, 1), lambda g, i, j: (g, 0),
-                             memory_space=pltpu.SMEM),
-            ],
-            out_specs=q_spec,
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=1,
+                grid=grid,
+                in_specs=[q_spec, kv_spec, kv_spec],
+                out_specs=q_spec,
+                scratch_shapes=scratch,
+            ),
             out_shape=jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
-            scratch_shapes=scratch,
             compiler_params=params,
             interpret=interpret,
-        )(qf, kf, vf, lens)
+        )(lens, qf, kf, vf)
     return out.reshape(b, h, sq, d)

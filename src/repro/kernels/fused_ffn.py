@@ -19,8 +19,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .pallas_compat import tpu_compiler_params as _tpu_compiler_params
-
 from .dense_matmul import _ACTIVATIONS
 
 __all__ = ["ffn_gateup_kernel", "ffn_gateup"]
@@ -79,7 +77,7 @@ def ffn_gateup(
             pltpu.VMEM((block_m, block_n), jnp.float32),
             pltpu.VMEM((block_m, block_n), jnp.float32),
         ],
-        compiler_params=_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,

@@ -19,7 +19,7 @@ import dataclasses
 import json
 import os
 import time
-from typing import Any, Callable, Dict, Optional, Sequence, Tuple
+from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -28,6 +28,7 @@ import numpy as np
 from ..obs import metrics as _metrics
 from . import ref as _ref
 from .bsr_matmul import bsr_matmul as _bsr_matmul
+from .conv2d import VMEM_LIMIT_BYTES as _CONV_VMEM_LIMIT
 from .conv2d import conv2d_gemm as _conv2d_gemm
 from .conv2d import (
     conv_out_hw,
@@ -39,7 +40,6 @@ from .dense_matmul import dense_matmul as _dense_matmul
 from .flash_attention import flash_attention as _flash_attention
 from .fused_elementwise import fused_elementwise as _fused_elementwise
 from .fused_ffn import ffn_gateup as _ffn_gateup
-from .pallas_compat import interpret_default
 from .quant_matmul import quant_matmul as _quant_matmul
 
 __all__ = [
@@ -63,8 +63,20 @@ __all__ = [
     "attention",
     "TuningCache",
     "tuning_cache",
+    "tune_rejected_counts",
     "set_tuning",
 ]
+
+
+def interpret_default() -> bool:
+    """Whether the kernel wrappers run Pallas in interpret mode: on whenever
+    the default backend is not a TPU, so every kernel stays parity-testable
+    on a CPU.  ``REPRO_PALLAS_INTERPRET`` forces it either way
+    (``chip_smoke.py`` refuses to run while it is set)."""
+    env = os.environ.get("REPRO_PALLAS_INTERPRET")
+    if env is not None:
+        return env not in ("0", "false", "False")
+    return jax.default_backend() != "tpu"
 
 
 def _flatten_batch(x: jax.Array) -> Tuple[jax.Array, Tuple[int, ...]]:
@@ -260,6 +272,7 @@ class TuningCache:
         stat["misses"] += 1
         if can_sweep:
             best, best_ms = None, float("inf")
+            rejected: Dict[str, int] = {}
             for cand in self.CANDIDATES[op]:
                 try:
                     jax.block_until_ready(runner(*cand))  # compile + warm
@@ -269,15 +282,25 @@ class TuningCache:
                         jax.block_until_ready(runner(*cand))
                         ts.append(time.perf_counter() - t0)
                     ms = float(np.median(ts)) * 1e3
-                except Exception:
-                    continue  # candidate invalid for this shape/backend
+                except Exception as e:
+                    # invalid for this shape/backend (e.g. a tile Mosaic
+                    # refuses): counted, never mistaken for "slow"
+                    err = type(e).__name__
+                    rejected[err] = rejected.get(err, 0) + 1
+                    _metrics.registry().counter(
+                        _TUNE_REJECTED_METRIC, op=op, error=err
+                    ).inc()
+                    continue
                 if ms < best_ms:
                     best, best_ms = cand, ms
             self.sweeps += 1
             stat["sweeps"] += 1
-            if best is not None:
-                self.entries[key] = TuneEntry(best, "swept", best_ms)
-                return best
+            if best is None:
+                raise RuntimeError(
+                    f"every {op} tuning candidate failed for {key}: {rejected}"
+                )
+            self.entries[key] = TuneEntry(best, "swept", best_ms)
+            return best
         default = default or self.DEFAULTS[op]
         self.entries[key] = TuneEntry(default, "default")
         return default
@@ -338,6 +361,16 @@ class TuningCache:
 
 
 _TUNING = TuningCache()
+
+#: sweep candidates that raised, by op family and exception type
+_TUNE_REJECTED_METRIC = "tune_rejected_total"
+
+
+def tune_rejected_counts() -> Dict[str, int]:
+    """Rejected tuning-sweep candidates keyed ``"op/error"`` -- a view over
+    the ``tune_rejected_total`` registry family."""
+    counts = _metrics.registry().label_counts(_TUNE_REJECTED_METRIC, "op", "error")
+    return {k: int(v) for k, v in counts.items()}
 
 
 def tuning_cache() -> TuningCache:
@@ -567,11 +600,6 @@ def qmatmul(
 # implicit-GEMM conv2d                                                          #
 # --------------------------------------------------------------------------- #
 
-#: per-grid-step VMEM working-set ceiling for the implicit-GEMM conv on real
-#: hardware (the whole padded image is tile-resident); interpret mode has no
-#: VMEM, so the guard only arms on TPUs
-_CONV_VMEM_LIMIT = 12 * 2**20
-
 #: conv2d lowering decisions live in the metrics registry, counted at trace
 #: time under jit:
 #:
@@ -628,6 +656,18 @@ def conv_gemm1x1_elected(kh: int, kw: int, groups: int, padding, c: int) -> bool
         return False
 
 
+def _hw_tiled_block_cs(c: int) -> List[int]:
+    """The tiled-K granularities a TPU can compile for ``c`` contracted
+    channels: a block's minor dim must be a 128-lane multiple (or the whole
+    dim), and a block as wide as ``c`` is the resident path."""
+    return sorted(
+        {
+            cand[2] for cand in TuningCache.CANDIDATES["conv2d"]
+            if len(cand) > 2 and cand[2] and cand[2] % 128 == 0 and cand[2] < c
+        }
+    )
+
+
 def conv_fallback_reason(
     c: int,
     h: int,
@@ -645,17 +685,18 @@ def conv_fallback_reason(
     block_h: Optional[int] = None,
     block_o: Optional[int] = None,
     block_c: Optional[int] = None,
+    n_sides: int = 0,
 ) -> Optional[str]:
     """The conv2d fallback matrix, shared by the :func:`conv2d` wrapper and
     :meth:`ExecutionPlan.memory_estimate` (a step that lowers through
     lax.conv has no Pallas VMEM workspace).  ``c`` is the *contracted*
     channel count.  The VMEM guard asks whether any resolvable configuration
-    fits: pinned blocks are honored verbatim; otherwise it evaluates the
-    default (block_h, block_o) at the most frugal K granularity available --
-    the smallest non-zero ``block_c`` sweep candidate (tiled-K caps the
-    resident slab, so wide-channel layers no longer trip the guard; sweep
-    candidates that individually overflow fail to compile and are skipped
-    by the sweep's try/except)."""
+    fits the kernel's scoped-VMEM budget (:data:`conv2d.VMEM_LIMIT_BYTES`,
+    the ``vmem_limit_bytes`` it compiles with) in Mosaic's tiled layout:
+    pinned blocks are honored verbatim; otherwise it evaluates the default
+    (block_h, block_o) resident, then at the smallest lane-aligned tiled-K
+    granularity.  ``tests/test_tpu_compile.py`` compiles the admitted app
+    shapes for the v5e."""
     if groups != 1:
         return "groups"
     if dilation != 1:
@@ -673,6 +714,8 @@ def conv_fallback_reason(
         return "padding"
     if oh < 1 or ow < 1:
         return "degenerate"
+    if not interpret and stride > 1 and x_itemsize < 4:
+        return "stride_narrow"  # Mosaic's strided load takes 32-bit data only
     if not interpret:
         dh, do_, _ = TuningCache.DEFAULTS["conv2d"]
         bh = block_h or dh
@@ -681,16 +724,12 @@ def conv_fallback_reason(
             c_options = [block_c]
         else:
             # resident first (cheapest when it fits), then the smallest
-            # tiled-K granularity the sweep could resolve
-            tiled = [
-                cand[2] for cand in TuningCache.CANDIDATES["conv2d"]
-                if len(cand) > 2 and cand[2]
-            ]
-            c_options = [0] + ([min(tiled)] if tiled else [])
+            # tiled-K granularity the chip can compile
+            c_options = [0] + _hw_tiled_block_cs(c)[:1]
         fits = any(
             conv_vmem_workspace(
                 c, h, w, kh, kw, stride, padding, bh, bo, bc,
-                x_itemsize=x_itemsize, w_itemsize=w_itemsize,
+                x_itemsize=x_itemsize, w_itemsize=w_itemsize, n_sides=n_sides,
             )["total"] <= _CONV_VMEM_LIMIT
             for bc in c_options
         )
@@ -710,29 +749,25 @@ def _conv_default_blocks(
     x_itemsize: int,
     w_itemsize: int,
     interpret: bool,
+    n_sides: int = 0,
 ) -> Tuple[int, int, int]:
     """Shape-aware conv default: the seeded (block_h, block_o) with the
     cheapest K granularity that fits VMEM -- resident when possible, else
-    the largest fitting tiled-K candidate (fewer grid steps), else the
-    smallest.  Interpret mode has no VMEM, so it always stays resident."""
+    the largest fitting lane-aligned tiled-K candidate (fewer grid steps),
+    else the smallest.  Interpret mode has no VMEM, so it always stays
+    resident."""
     dh, do_, _ = TuningCache.DEFAULTS["conv2d"]
     if interpret:
         return (dh, do_, 0)
-    tiled = sorted(
-        {
-            cand[2] for cand in TuningCache.CANDIDATES["conv2d"]
-            if len(cand) > 2 and cand[2]
-        },
-        reverse=True,
-    )
-    for bc in (0, *tiled):
+    tiled = _hw_tiled_block_cs(c)
+    for bc in (0, *reversed(tiled)):
         total = conv_vmem_workspace(
             c, h, w, kh, kw, stride, padding, dh, do_, bc,
-            x_itemsize=x_itemsize, w_itemsize=w_itemsize,
+            x_itemsize=x_itemsize, w_itemsize=w_itemsize, n_sides=n_sides,
         )["total"]
         if total <= _CONV_VMEM_LIMIT:
             return (dh, do_, bc)
-    return (dh, do_, min(tiled) if tiled else 0)  # guard rejects this case
+    return (dh, do_, tiled[0] if tiled else 0)  # guard rejects this case
 
 
 def _conv2d_fallback(
@@ -852,10 +887,12 @@ def conv2d(
     Fallback matrix (auto-routed through ``lax.conv``, bit-identical math,
     counted in :func:`conv_fallback_counts`): ``groups != 1``,
     ``dilation != 1``, malformed/negative explicit padding, degenerate
-    output (``OH*OW < 1``), or -- on real hardware only -- a per-step VMEM
-    working set above ~12 MB at every resolvable K granularity (tiled-K
-    caps the resident slab at ``block_c`` channels, so only pathological
-    spatial extents still trip this).
+    output (``OH*OW < 1``), or -- on real hardware only -- a strided conv
+    over int8 (W8A8) activations, or a per-step VMEM
+    working set (Mosaic's tiled layout, double-buffered blocks) above
+    :data:`conv2d.VMEM_LIMIT_BYTES` at every resolvable K granularity.  The
+    whole padded image of one batch element is resident, so frames much
+    above 256x256 trip this.
 
     Block sizes left as ``None`` resolve through the tuning cache under the
     ``conv2d|NxCxHxWxOxKHxKWxS|{dtype}|{fmt}+{scheme}[+valid|+p..][+e..s..]|{mode}``
@@ -896,7 +933,7 @@ def conv2d(
         groups=groups, dilation=dilation, interpret=interpret,
         x_itemsize=1 if scheme == "w8a8" else x.dtype.itemsize,
         w_itemsize=w.dtype.itemsize, block_h=block_h, block_o=block_o,
-        block_c=block_c,
+        block_c=block_c, n_sides=len(sides),
     )
     if reason is not None:
         _metrics.registry().counter(_CONV_FALLBACK_METRIC, reason=reason).inc()
@@ -996,7 +1033,7 @@ def conv2d(
             interpret, runner,
             default=_conv_default_blocks(
                 c, h, w_in, kh, kw_, stride, padding, x_item,
-                w.dtype.itemsize, interpret,
+                w.dtype.itemsize, interpret, n_sides=len(sides),
             ),
         ))
     elif block_h is None or block_o is None or block_c is None:

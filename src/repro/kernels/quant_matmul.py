@@ -50,7 +50,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .dense_matmul import _ACTIVATIONS, apply_epilogue_steps, validate_epilogue
-from .pallas_compat import tpu_compiler_params as _tpu_compiler_params
 
 __all__ = [
     "quant_matmul_kernel",
@@ -102,8 +101,8 @@ def quant_matmul_kernel(
 
 
 def quant_matmul_pipelined_kernel(
-    x_hbm,  # [bm, K] int8 (W8A8) or f32 (W8-only) row panel in HBM
-    w_hbm,  # [K, bn] int8 column panel in HBM
+    x_hbm,  # [M, K] int8 (W8A8) or f32 (W8-only), whole operand in HBM
+    w_hbm,  # [K, N] int8, whole operand in HBM
     ws_ref,  # [1, bn] f32 combined rescale per output column
     b_ref,
     side_refs,
@@ -125,17 +124,18 @@ def quant_matmul_pipelined_kernel(
     per-column rescale + epilogue run once after the loop."""
     a8 = jnp.issubdtype(x_hbm.dtype, jnp.integer)
 
+    bm, bn = o_ref.shape
+    rows = pl.ds(pl.multiple_of(pl.program_id(0) * bm, bm), bm)
+    cols = pl.ds(pl.multiple_of(pl.program_id(1) * bn, bn), bn)
+
     def copies(slot, step):
+        ks = pl.ds(pl.multiple_of(step * block_k, block_k), block_k)
         return (
             pltpu.make_async_copy(
-                x_hbm.at[:, pl.ds(step * block_k, block_k)],
-                x_slots.at[slot],
-                sem.at[slot, 0],
+                x_hbm.at[rows, ks], x_slots.at[slot], sem.at[slot, 0]
             ),
             pltpu.make_async_copy(
-                w_hbm.at[pl.ds(step * block_k, block_k), :],
-                w_slots.at[slot],
-                sem.at[slot, 1],
+                w_hbm.at[ks, cols], w_slots.at[slot], sem.at[slot, 1]
             ),
         )
 
@@ -223,10 +223,9 @@ def quant_matmul(
     pipelined = pipeline >= 2
     if pipelined:
         grid = (m // block_m, n // block_n)
-        any_space = pltpu.TPUMemorySpace.ANY
         in_specs = [
-            pl.BlockSpec((block_m, k), lambda i, j: (i, 0), memory_space=any_space),
-            pl.BlockSpec((k, block_n), lambda i, j: (0, j), memory_space=any_space),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec((1, block_n), lambda i, j: (0, j)),
         ]
         bias_tile = pl.BlockSpec((1, block_n), lambda i, j: (0, j))
@@ -300,7 +299,7 @@ def quant_matmul(
         out_specs=out_tile,
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=scratch,
-        compiler_params=_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=semantics
         ),
         interpret=interpret,

@@ -1,1 +1,1 @@
-from .mesh import HW, make_mesh, make_production_mesh
+from .mesh import PEAKS, make_mesh, make_production_mesh, peaks

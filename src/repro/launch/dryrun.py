@@ -1,6 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run (deliverable e): lower + compile every
 (architecture x input-shape x mesh) cell against the production mesh using
 ShapeDtypeStruct inputs -- no allocation, real SPMD partitioning.
@@ -29,6 +26,7 @@ import argparse
 import dataclasses
 import gc
 import json
+import os
 import time
 import traceback
 from typing import Any, Dict, Optional, Tuple
@@ -44,7 +42,9 @@ from ..models.sharding import FSDP_RULES, batch_spec, param_pspecs
 from ..training.optimizer import AdamWConfig, AdamWState, adamw_init, adamw_update, zero1_pspecs
 from ..utils.flops import model_flops, param_counts
 from ..utils.hlo import collective_bytes
-from .mesh import HW, make_production_mesh
+from .mesh import TARGET_KIND, make_production_mesh, peaks
+
+HW = peaks(TARGET_KIND)
 
 RESULTS_DIR = os.path.join(
     os.path.dirname(__file__), "..", "..", "..", "results", "dryrun"
@@ -141,7 +141,7 @@ def _build(cfg, shape_name: str, mesh, *, zero1: bool, remat: bool, scan: bool,
     from ..utils.flops import param_counts as _pc
 
     per_chip_tp = _pc(cfg, params_shapes)["total"] * 2 / mesh.shape["model"]
-    rules = FSDP_RULES if per_chip_tp > HW.HBM_BYTES / 4 else None
+    rules = FSDP_RULES if per_chip_tp > HW.hbm_bytes / 4 else None
     ro = overrides.get("rules")
     if ro is not None:
         if isinstance(ro, str):
@@ -243,9 +243,7 @@ def _compile_once(cfg, shape_name, mesh, *, zero1, remat, scan, overrides=None):
         compiled = lowered.compile()
     t2 = time.time()
     mem = compiled.memory_analysis()
-    from ..utils.jax_compat import cost_analysis
-
-    ca = cost_analysis(compiled)
+    ca = compiled.cost_analysis()
     total, per_kind = collective_bytes(compiled.as_text())
     out = {
         "step": step_name,
@@ -265,7 +263,7 @@ def _compile_once(cfg, shape_name, mesh, *, zero1, remat, scan, overrides=None):
     }
     live = mem.argument_size_in_bytes + mem.temp_size_in_bytes - mem.alias_size_in_bytes
     out["memory"]["live_bytes"] = int(live)
-    out["memory"]["fits_hbm"] = bool(live < HW.HBM_BYTES)
+    out["memory"]["fits_hbm"] = bool(live < HW.hbm_bytes)
     del compiled, lowered
     gc.collect()
     return out
@@ -397,6 +395,10 @@ def cell_path(out_dir: str, arch: str, shape: str, mesh: str) -> str:
 
 
 def main() -> None:
+    # 512 host devices for the production meshes: set before the first
+    # backend init, never at import (importing this module must leave the
+    # process's device state alone)
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=ARCH_IDS)
     ap.add_argument("--shape", choices=list(SHAPES))

@@ -67,6 +67,9 @@ def build_app_plan(args):
 
 def main() -> None:
     from ..obs import profile_plan
+    from ..utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--graph-app",

@@ -22,7 +22,9 @@ import json
 import os
 from typing import Any, Dict, List, Optional
 
-from .mesh import HW
+from .mesh import TARGET_KIND, peaks
+
+HW = peaks(TARGET_KIND)
 
 __all__ = ["analyze_record", "build_table", "main"]
 
@@ -36,9 +38,9 @@ def analyze_record(rec: Dict[str, Any]) -> Optional[Dict[str, Any]]:
     flops_dev = cost["flops"]
     bytes_dev = cost["bytes_accessed"]
     coll_dev = coll["total_bytes"]
-    t_compute = flops_dev / HW.PEAK_FLOPS
-    t_memory = bytes_dev / HW.HBM_BW
-    t_collective = coll_dev / HW.ICI_BW
+    t_compute = flops_dev / HW.peak_flops
+    t_memory = bytes_dev / HW.hbm_bw
+    t_collective = coll_dev / HW.ici_bw
     terms = {"compute": t_compute, "memory": t_memory, "collective": t_collective}
     dominant = max(terms, key=terms.get)
     model_fl = rec["model_flops"]
@@ -47,8 +49,8 @@ def analyze_record(rec: Dict[str, Any]) -> Optional[Dict[str, Any]]:
     # ideal step time = max(model FLOPs at peak, every argument byte read
     # once at HBM bw) -- decode is *legitimately* memory-bound (weights + KV
     # must stream), so a compute-only ideal would be meaningless there.
-    t_ideal_c = model_fl / (chips * HW.PEAK_FLOPS)
-    t_ideal_m = rec["memory"]["argument_bytes"] / HW.HBM_BW
+    t_ideal_c = model_fl / (chips * HW.peak_flops)
+    t_ideal_m = rec["memory"]["argument_bytes"] / HW.hbm_bw
     t_ideal = max(t_ideal_c, t_ideal_m)
     bound = max(terms.values())
     frac = t_ideal / bound if bound > 0 else 0.0

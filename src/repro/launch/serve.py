@@ -30,7 +30,9 @@ import numpy as np
 from ..configs import ARCH_IDS, get_config, smoke_config
 from ..models import get_model
 from ..serving.engine import Engine, Request, RequestScheduler
+from ..utils.compile_cache import enable_compile_cache
 from ..utils.fileio import atomic_write_json
+from . import parity
 
 
 class _MetricsDump:
@@ -89,6 +91,26 @@ class _MetricsDump:
               f"(load in Perfetto / chrome://tracing)")
 
 
+def _pick_backend(args) -> str:
+    """The plan backend this run serves on, printed as its first line: the
+    Pallas kernels on a TPU (``quant`` under ``--quantize``), the jnp
+    ``reference`` backend elsewhere -- interpret-mode Pallas on a CPU would
+    time Python, not the model.  ``--guarded`` (async / llm) serves the
+    guarded backend on any platform; the default LM demo runs the model's
+    jnp forward through :class:`Engine`."""
+    platform = jax.default_backend()
+    if not (args.graph_app or args.async_serve or args.llm):
+        backend = "engine"
+    elif args.guarded and (args.async_serve or args.llm):
+        backend = "guarded"
+    elif platform != "tpu":
+        backend = "reference"
+    else:
+        backend = "quant" if args.quantize and not args.async_serve else "kernel"
+    print(f"serve: platform={platform} backend={backend}")
+    return backend
+
+
 def _serve_graph_app(args) -> None:
     """Compile one of the paper's demo apps through the full pipeline
     (PassManager -> execution plan) and serve frames through the plan."""
@@ -103,10 +125,7 @@ def _serve_graph_app(args) -> None:
     go = pm.run(g, ctx)
     print(pm.summary(ctx))
 
-    # kernel backend on real TPUs; jnp reference elsewhere (interpret-mode
-    # Pallas on CPU would measure Python, not the model)
-    on_tpu = jax.default_backend() == "tpu"
-    backend = "kernel" if on_tpu else "reference"
+    backend = args.backend
     c_in = 1 if args.graph_app == "coloring" else 3
     shape = (args.batch, c_in, args.size, args.size)
     rng = np.random.default_rng(args.seed)
@@ -128,7 +147,6 @@ def _serve_graph_app(args) -> None:
             act_quant_skip=APP_ACT_SKIP[args.graph_app],
         )
         gq = PassManager(("quantize",)).run(go, qctx)
-        backend = "quant" if on_tpu else "reference"
         plan = compile_plan(gq, backend=backend)
         # plan-level parity + storage stats vs the fp32 reference plan
         probe = jnp.asarray(rng.standard_normal(shape), jnp.float32)
@@ -234,11 +252,10 @@ def _serve_async(args) -> None:
             "--quantize"
         )
     apps = [args.graph_app] if args.graph_app else list(APPS)
-    on_tpu = jax.default_backend() == "tpu"
     # --guarded serves degradation-tolerant plans: each step tries the
     # kernel/quant handler and demotes failures to the jnp reference (with
     # circuit breakers + numeric guards); stats land in server.health()
-    backend = "guarded" if args.guarded else ("kernel" if on_tpu else "reference")
+    backend = args.backend
     batch_size = args.batch_size or 4
     rng = np.random.default_rng(args.seed)
 
@@ -296,10 +313,12 @@ def _serve_async(args) -> None:
             h.result()
         dt = time.time() - t0
         for app, (x, h) in probes.items():
+            # async path == direct execution, within the platform's bound
             plan, params = plans[app]
-            err = float(jnp.max(jnp.abs(jnp.asarray(h.result())
-                                        - jnp.asarray(plan(params, x[None]))[0])))
-            assert err <= 1e-5, (app, err)  # async path == direct execution
+            with jax.default_matmul_precision("highest"):
+                want = np.asarray(plan(params, x[None]))[0]
+            err, bound = parity.frame_error(h.result(), want, jax.default_backend())
+            assert err <= bound, (app, err, bound)
         s = server.stats
         print(f"async: {len(handles)} requests over {len(apps)} plans in "
               f"{dt:.3f}s ({len(handles) / dt:.1f} req/s), "
@@ -375,15 +394,14 @@ def _serve_llm(args) -> None:
     forward loop."""
     from ..core.graph import compile_plan
     from ..core.graph.passes import optimize
-    from ..models.transformer import forward, init_lm
+    from ..models.transformer import init_lm
     from ..models.transformer_graph import build_decoder_graph, decoder_cache_spec
     from ..serving import AsyncPlanServer, PagedKVCache
 
     cfg = smoke_config(args.arch) if args.smoke else get_config(args.arch)
     params = init_lm(jax.random.PRNGKey(args.seed), cfg)
-    on_tpu = jax.default_backend() == "tpu"
-    backend = "guarded" if args.guarded else ("kernel" if on_tpu else "reference")
-    interpret = backend != "reference" and not on_tpu
+    backend = args.backend
+    interpret = backend != "reference" and jax.default_backend() != "tpu"
 
     go_pre = optimize(build_decoder_graph(params, cfg, phase="prefill"))
     go_dec = optimize(build_decoder_graph(params, cfg, phase="decode"))
@@ -432,19 +450,18 @@ def _serve_llm(args) -> None:
           f"peak_used={occ['peak_used']} leaked={occ['used_pages']}")
     cache.check_invariants()
 
-    # greedy-parity probe: the served tokens == a plain jnp forward loop
-    seq = list(int(t) for t in prompts[0])
-    for _ in range(args.new_tokens):
-        logits, _ = forward(params, cfg, jnp.asarray([seq], jnp.int32))
-        nxt = int(jnp.argmax(logits[0, -1]))
-        seq.append(nxt)
-    want = seq[len(prompts[0]):]
-    got = [int(t) for t in handles[0].result()]
-    assert got == want, (got, want)
-    print(f"llm: greedy parity ok ({len(got)} tokens match the jnp loop)")
+    # greedy-parity probe: the served tokens == greedy decoding of the jnp
+    # model (on a TPU, up to near-ties inside the logit bound)
+    tol = parity.logit_tolerance(jax.default_backend())
+    served = [[int(t) for t in h.result()] for h in handles]
+    agree = parity.greedy_agreement(params, cfg, prompts, served, tol)
+    assert agree["worst_miss"] == 0.0, agree
+    print(f"llm: greedy parity ok ({agree['match']}/{agree['total']} tokens "
+          f"match the jnp model, {agree['near_ties']} near-ties within {tol})")
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=ARCH_IDS, default="qwen2.5-3b")
     ap.add_argument("--smoke", action="store_true")
@@ -519,6 +536,7 @@ def main() -> None:
     ap.add_argument("--metrics-interval", type=float, default=0.5,
                     help="seconds between --metrics-dump registry snapshots")
     args = ap.parse_args()
+    args.backend = _pick_backend(args)
 
     if args.metrics_dump and (args.async_serve or args.graph_app or args.llm):
         with _MetricsDump(args.metrics_dump, args.metrics_interval):

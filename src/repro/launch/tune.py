@@ -33,6 +33,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..kernels import ops as kops
+from ..utils.compile_cache import enable_compile_cache
 
 
 def _sweep_app(app: str, args) -> None:
@@ -73,6 +74,7 @@ def _sweep_app(app: str, args) -> None:
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
     ap.add_argument("--graph-app",
                     choices=["style_transfer", "coloring", "super_resolution", "all"],

@@ -203,7 +203,15 @@ def init_embedding(key: Array, vocab: int, d: int, dtype=jnp.bfloat16) -> Params
 
 
 def embed(p: Params, tokens: Array) -> Array:
-    return jnp.take(p["table"], tokens, axis=0)
+    """Row gather ``table[tokens]``.  On a mesh with explicit axes the
+    gather's output sharding is ambiguous (vocab-sharded table, batch-
+    sharded ids), so it is pinned to the ids' sharding with the feature
+    axis replicated."""
+    sh = jax.typeof(tokens).sharding
+    if jax.sharding.AxisType.Explicit not in sh.mesh.axis_types:
+        return jnp.take(p["table"], tokens, axis=0)
+    out = jax.sharding.NamedSharding(sh.mesh, jax.sharding.PartitionSpec(*sh.spec, None))
+    return p["table"].at[tokens].get(out_sharding=out)
 
 
 # --------------------------------------------------------------------------- #

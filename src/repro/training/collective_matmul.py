@@ -21,7 +21,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from ..utils.jax_compat import axis_size, shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 __all__ = ["ag_matmul", "rs_matmul", "make_overlapped_tp_matmuls"]
@@ -36,7 +36,7 @@ def ag_matmul(x_local: Array, w_local: Array, axis_name: str) -> Array:
     w_local: [k, n_loc] (this device's column shard of W)
     returns: [m_loc * N, n_loc] (all X rows against the local W columns)
     """
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     m_loc = x_local.shape[0]
     out = jnp.zeros((n * m_loc, w_local.shape[1]), x_local.dtype)
@@ -63,7 +63,7 @@ def rs_matmul(x_local: Array, w_local: Array, axis_name: str) -> Array:
     Ring schedule: at each step, add the partial for the shard the running
     buffer is about to visit, then permute the buffer.
     """
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     m = x_local.shape[0]
     m_loc = m // n

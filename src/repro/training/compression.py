@@ -23,8 +23,8 @@ from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-from ..utils.jax_compat import shard_map
 
 __all__ = ["CompressionConfig", "init_error_feedback", "quantize_int8", "dequantize_int8",
            "compressed_mean_grads", "make_compressed_allreduce"]

@@ -22,8 +22,8 @@ from typing import Any, Callable, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-from ..utils.jax_compat import shard_map
 
 __all__ = ["pipeline_forward", "make_pipelined_loss"]
 

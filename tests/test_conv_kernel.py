@@ -371,6 +371,8 @@ def test_launch_tune_smoke_prewarms_and_saves_cache(tmp_path, monkeypatch):
         ["tune", "--graph-app", "coloring", "--smoke", "--size", "8",
          "--out", str(out)],
     )
+    # the CLI turns on the persistent compile cache; a test never does
+    monkeypatch.setattr(tune, "enable_compile_cache", lambda: None)
     try:
         tune.main()
         assert out.exists()
@@ -451,7 +453,7 @@ def test_wide_channel_conv_no_longer_vmem_fallback():
     """PR 4's guard rejected any shape whose resident full-K workspace
     overflowed VMEM; with tiled-K the guard passes whenever SOME block_c
     candidate fits, so the wide-channel config lowers through Pallas."""
-    c, h, w, kh = 2048, 32, 32, 3
+    c, h, w, kh = 2048, 64, 64, 3
     # the resident workspace genuinely overflows (the old fallback trigger)
     resident = kops.conv_vmem_workspace(c, h, w, kh, kh, 1, "SAME", 8, 128)
     assert resident["total"] > kops._CONV_VMEM_LIMIT

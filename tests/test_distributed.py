@@ -33,6 +33,7 @@ def test_sharded_train_step_small_mesh():
     """pjit train step on (2 data, 2 model): loss decreases, params sharded."""
     out = _run("""
         import jax, jax.numpy as jnp
+        from repro.launch.mesh import make_mesh
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.configs import smoke_config
         from repro.models import get_model
@@ -41,7 +42,7 @@ def test_sharded_train_step_small_mesh():
         from repro.training.optimizer import AdamWConfig
         from repro.training.train_loop import init_train_state, make_train_step
 
-        mesh = jax.make_mesh((2, 2), ("data", "model"))
+        mesh = make_mesh((2, 2), ("data", "model"))
         cfg = smoke_config("qwen2.5-3b")
         model = get_model(cfg)
         params = model.init(jax.random.PRNGKey(0))
@@ -70,9 +71,10 @@ def test_sharded_train_step_small_mesh():
 def test_compressed_allreduce_multi_device():
     out = _run("""
         import jax, jax.numpy as jnp
+        from repro.launch.mesh import make_mesh
         from repro.training.compression import (CompressionConfig,
             make_compressed_allreduce)
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = make_mesh((8,), ("data",))
         tmpl = {"w": jnp.zeros((16, 32))}
         g = {"w": jax.random.normal(jax.random.PRNGKey(0), (8, 16, 32))}
         err = {"w": jnp.zeros((8, 16, 32))}
@@ -96,8 +98,9 @@ def test_compressed_allreduce_multi_device():
 def test_pipeline_parallel_grad_exactness():
     out = _run("""
         import jax, jax.numpy as jnp
+        from repro.launch.mesh import make_mesh
         from repro.training.pipeline_parallel import make_pipelined_loss, pipeline_forward
-        mesh = jax.make_mesh((4,), ("pipe",))
+        mesh = make_mesh((4,), ("pipe",))
         L, D, M, mb = 8, 16, 4, 4
         params = {"w": jax.random.normal(jax.random.PRNGKey(2), (L, D, D)) * 0.2}
         layer_fn = lambda lp, h: jnp.tanh(h @ lp["w"])
@@ -126,6 +129,7 @@ def test_elastic_checkpoint_restore_other_mesh(tmp_path):
     """Save on a (4 data, 1 model) mesh, restore onto (2 data, 2 model)."""
     out = _run(f"""
         import jax, jax.numpy as jnp
+        from repro.launch.mesh import make_mesh
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.configs import smoke_config
         from repro.models import get_model
@@ -135,12 +139,12 @@ def test_elastic_checkpoint_restore_other_mesh(tmp_path):
         cfg = smoke_config("granite-3-2b")
         model = get_model(cfg)
         params = model.init(jax.random.PRNGKey(0))
-        mesh_a = jax.make_mesh((4, 1), ("data", "model"))
+        mesh_a = make_mesh((4, 1), ("data", "model"))
         sh_a = jax.tree.map(lambda s: NamedSharding(mesh_a, s), param_pspecs(params))
         params_a = jax.tree.map(jax.device_put, params, sh_a)
         save({str(tmp_path)!r}, 7, params_a)
 
-        mesh_b = jax.make_mesh((2, 2), ("data", "model"))
+        mesh_b = make_mesh((2, 2), ("data", "model"))
         sh_b = jax.tree.map(lambda s: NamedSharding(mesh_b, s), param_pspecs(params))
         restored, at = restore({str(tmp_path)!r}, params, shardings=sh_b)
         assert at == 7
@@ -181,12 +185,25 @@ def test_dryrun_cell_end_to_end(tmp_path):
     assert 0 < a["useful_ratio"] < 10
 
 
+def test_peaks_table_keyed_by_device_kind():
+    """The roofline denominators are looked up by ``device_kind``, with
+    their source; a device that is not in the table raises."""
+    from repro.launch.mesh import peaks
+
+    v5e = peaks("TPU v5 lite")
+    assert v5e.peak_flops == 197e12 and v5e.hbm_bw == 819e9
+    assert "TPU v5e" in v5e.source
+    with pytest.raises(KeyError, match="no published peaks"):
+        peaks("cpu")
+
+
 def test_overlapped_collective_matmul():
     """Ring AG-matmul / RS-matmul == gathered reference, grads exact."""
     out = _run("""
         import jax, jax.numpy as jnp
+        from repro.launch.mesh import make_mesh
         from repro.training.collective_matmul import make_overlapped_tp_matmuls
-        mesh = jax.make_mesh((4,), ("model",))
+        mesh = make_mesh((4,), ("model",))
         ag, rs = make_overlapped_tp_matmuls(mesh)
         x = jax.random.normal(jax.random.PRNGKey(0), (16, 32))
         w = jax.random.normal(jax.random.PRNGKey(1), (32, 24)) * 0.1
@@ -195,6 +212,29 @@ def test_overlapped_collective_matmul():
         g = jax.grad(lambda x, w: jnp.sum(ag(x, w) ** 2))(x, w)
         g_ref = jax.grad(lambda x, w: jnp.sum((x @ w) ** 2))(x, w)
         assert float(jnp.abs(g - g_ref).max()) < 1e-5
+        print("OK")
+    """, devices=4)
+    assert "OK" in out
+
+
+def test_embed_gather_on_explicit_mesh():
+    """On a mesh with explicit axes (jax.make_mesh's default) the embedding
+    gather of a vocab-sharded table by batch-sharded ids is pinned to the
+    ids' sharding and equals the unsharded gather."""
+    out = _run("""
+        import jax, jax.numpy as jnp
+        from jax.sharding import NamedSharding, PartitionSpec as P
+        from repro.models.layers import embed
+
+        mesh = jax.make_mesh((2, 2), ("data", "model"))
+        table = jnp.arange(64 * 16, dtype=jnp.float32).reshape(64, 16)
+        ids = jax.random.randint(jax.random.PRNGKey(0), (8, 5), 0, 64)
+        want = embed({"table": table}, ids)
+        ts = jax.device_put(table, NamedSharding(mesh, P("model", None)))
+        ids_s = jax.device_put(ids, NamedSharding(mesh, P("data", None)))
+        got = jax.jit(lambda t, i: embed({"table": t}, i))(ts, ids_s)
+        assert got.sharding.spec == P("data", None, None), got.sharding
+        assert bool((got == want).all())
         print("OK")
     """, devices=4)
     assert "OK" in out

@@ -81,16 +81,21 @@ def test_fused_elementwise_node_kernel_vs_reference_backend():
 
 def test_fused_elementwise_kernel_falls_back_on_broadcast_sides():
     """Sides that only broadcast (not same-shape) cannot stream per-tile;
-    the kernel handler must fall back to the interpreter, not crash."""
+    the kernel handler must fall back to the interpreter, not crash, and
+    the jnp route is counted with its reason."""
+    from repro.core.graph.executor import jnp_route_counts
+
     n1 = Node(op="fused_elementwise", name="f", inputs=("x", "y"),
               attrs={"steps": (("add", 1), ("activation", "relu"))})
     g = Graph(nodes=[n1], inputs=("x", "y"), outputs=("f",))
     x = jax.random.normal(KEY, (4, 16))
     y = jax.random.normal(jax.random.PRNGKey(1), (16,))  # broadcasts over rows
+    before = jnp_route_counts().get("fused_elementwise/broadcast_side", 0)
     got = compile_plan(g, backend="kernel", interpret=True)(g.params, x, y)
     np.testing.assert_allclose(
         np.asarray(got), np.asarray(jax.nn.relu(x + y)), rtol=1e-6
     )
+    assert jnp_route_counts()["fused_elementwise/broadcast_side"] == before + 1
 
 
 @pytest.mark.parametrize("app", list(APPS))

@@ -1,0 +1,251 @@
+"""Every Pallas kernel of the chip path compiles for a TPU v5e.
+
+Lowered with ``interpret=False`` on ``ShapeDtypeStruct``s placed on one chip
+of a described (not attached) ``v5e:2x2`` topology, then compiled by the
+TPU compiler installed with jaxlib: Mosaic refuses here what it would refuse
+on the chip (unaligned slices, strided loads it cannot lower, more scoped
+VMEM than a kernel may use), at no chip time.  Nothing runs, so nothing here
+says anything about results or speed.
+
+The topology is described inside a module fixture, never at import: one
+process at a time may load the TPU library, and every test worker imports
+this file.  All such compiles live in this one file.
+"""
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.core.graph import PassContext, PassManager, compile_plan
+from repro.kernels import ops as kops
+from repro.kernels.bsr_matmul import bsr_matmul
+from repro.kernels.conv2d import conv2d_gemm
+from repro.kernels.dense_matmul import dense_matmul
+from repro.kernels.flash_attention import flash_attention
+from repro.kernels.fused_elementwise import fused_elementwise
+from repro.kernels.fused_ffn import ffn_gateup
+from repro.kernels.quant_matmul import quant_matmul
+from repro.models.cnn import APP_ACT_SKIP, APP_QUANT_SKIP, APPS, app_masks
+
+F32, I8, I32 = jnp.float32, jnp.int8, jnp.int32
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _compile(one_chip, fn, *specs):
+    """Lower ``fn`` over ``specs`` (``(shape, dtype)`` or None) on one
+    described chip and compile it; returns the compiled HLO text."""
+    live = [i for i, s in enumerate(specs) if s is not None]
+
+    def call(*arrays):
+        args = [None] * len(specs)
+        for i, a in zip(live, arrays):
+            args[i] = a
+        return fn(*args)
+
+    sds = [jax.ShapeDtypeStruct(*specs[i], sharding=one_chip) for i in live]
+    return jax.jit(call).lower(*sds).compile().as_text()
+
+
+def _assert_kernel(one_chip, fn, *specs):
+    assert "tpu_custom_call" in _compile(one_chip, fn, *specs)
+
+
+# --------------------------------------------------------------------------- #
+# the decoder's kernels at qwen2.5-3b widths (d 2048, dh 128, d_ff 11008)     #
+# --------------------------------------------------------------------------- #
+
+
+@pytest.mark.parametrize("pipeline", [1, 2])
+def test_dense_matmul_compiles(one_chip, pipeline):
+    """Grid-K and the double-buffered HBM->VMEM ring, 2048x2048 projection."""
+    _assert_kernel(
+        one_chip,
+        lambda x, w, b: dense_matmul(
+            x, w, b, activation="relu", pipeline=pipeline, interpret=False
+        ),
+        ((256, 2048), F32), ((2048, 2048), F32), ((2048,), F32),
+    )
+
+
+def test_dense_matmul_epilogue_side_compiles(one_chip):
+    _assert_kernel(
+        one_chip,
+        lambda x, w, b, s: dense_matmul(
+            x, w, b, s, epilogue=(("add", 0),), interpret=False
+        ),
+        ((256, 2048), F32), ((2048, 2048), F32), ((2048,), F32), ((256, 2048), F32),
+    )
+
+
+@pytest.mark.parametrize("pipeline", [1, 2])
+@pytest.mark.parametrize("x_dtype", [F32, I8], ids=["w8", "w8a8"])
+def test_quant_matmul_compiles(one_chip, x_dtype, pipeline):
+    _assert_kernel(
+        one_chip,
+        lambda x, w, ws, b: quant_matmul(
+            x, w, ws, b, pipeline=pipeline, block_k=256, interpret=False
+        ),
+        ((512, 512), x_dtype), ((512, 128), I8), ((128,), F32), ((128,), F32),
+    )
+
+
+def test_bsr_matmul_128_blocks_compiles(one_chip):
+    _assert_kernel(
+        one_chip,
+        lambda x, v, r, b: bsr_matmul(x, v, r, b, interpret=False),
+        ((256, 1024), F32), ((8, 4, 128, 128), F32), ((8, 4), I32), ((1024,), F32),
+    )
+
+
+def test_fused_elementwise_compiles(one_chip):
+    steps = (("add", 0), ("norm", 0, 1e-5), ("activation", "relu"))
+    _assert_kernel(
+        one_chip,
+        lambda x, s, sc, bi: fused_elementwise(
+            x, s, sc, bi, steps=steps, n_norms=1, d_true=256, interpret=False
+        ),
+        ((1024, 256), F32), ((1024, 256), F32), ((1, 256), F32), ((1, 256), F32),
+    )
+
+
+@pytest.mark.parametrize(
+    "phase,sq,block_q,causal",
+    [("prefill", 128, 128, True), ("decode", 8, 8, False)],
+)
+def test_flash_attention_compiles(one_chip, phase, sq, block_q, causal):
+    """4 sequences x 16 heads x dh 128 over a 128-slot KV span, masked by
+    per-sequence lengths (the paged-cache path)."""
+    _assert_kernel(
+        one_chip,
+        lambda q, k, v, lens: flash_attention(
+            q, k, v, lens, causal=causal, block_q=block_q, interpret=False
+        ),
+        ((4, 16, sq, 128), F32), ((4, 16, 128, 128), F32), ((4, 16, 128, 128), F32),
+        ((4,), I32),
+    )
+
+
+def test_fused_ffn_gateup_compiles(one_chip):
+    _assert_kernel(
+        one_chip,
+        lambda x, g, u: ffn_gateup(x, g, u, interpret=False),
+        ((256, 2048), F32), ((2048, 11008), F32), ((2048, 11008), F32),
+    )
+
+
+# --------------------------------------------------------------------------- #
+# conv2d: a style-transfer residual layer at 512^2 (128^2 after two stride-2  #
+# stages), batch 4                                                            #
+# --------------------------------------------------------------------------- #
+
+
+@pytest.mark.parametrize(
+    "c,block_c", [(64, 0), (256, 128)], ids=["resident", "tiled_k"]
+)
+def test_conv2d_compiles(one_chip, c, block_c):
+    """The 3x3 residual body at its pruned width (64 of 128 input channels,
+    resident), and at twice the builder's width with the K contraction
+    tiled in 128-channel slabs."""
+    _assert_kernel(
+        one_chip,
+        lambda x, w, b: conv2d_gemm(
+            x, w, None, b, kh=3, kw=3, activation="relu", block_c=block_c,
+            interpret=False,
+        ),
+        ((4, 130, 130, c), F32), ((9, c, 128), F32), ((128,), F32),
+    )
+
+
+def test_conv2d_guard_rejection_matches_mosaic(one_chip):
+    """The guard's other side: style transfer's 7x7 stem at 512^2 keeps a
+    whole padded frame resident, and Mosaic refuses it for scoped VMEM --
+    the guard routes it to lax.conv instead of attempting the kernel."""
+    assert kops.conv_fallback_reason(3, 512, 512, 7, 7, 1, "SAME", interpret=False) == "vmem"
+    with pytest.raises(Exception, match="RESOURCE_EXHAUSTED|vmem|VMEM"):
+        _compile(
+            one_chip,
+            lambda x, w, b: conv2d_gemm(x, w, None, b, kh=7, kw=7, interpret=False),
+            ((1, 518, 518, 3), F32), ((49, 3, 128), F32), ((128,), F32),
+        )
+
+
+# --------------------------------------------------------------------------- #
+# every kernel call the frame plans make at the chip run's sizes              #
+# --------------------------------------------------------------------------- #
+
+#: (app, input side): super resolution upsamples 256 -> 512
+_FRAME_CASES = [
+    ("style_transfer", 512), ("coloring", 512), ("super_resolution", 256),
+]
+
+
+def _record_kernel_calls(monkeypatch):
+    """Patch the Pallas entry points ``ops`` calls to record each call's
+    operand shapes and static arguments (then call through)."""
+    calls = {}
+
+    def recorder(fn):
+        def wrapped(*args, **kw):
+            spec = tuple(None if a is None else (a.shape, a.dtype) for a in args)
+            key = (fn.__name__, spec, tuple(sorted(kw.items(), key=lambda t: t[0])))
+            calls.setdefault(key, (fn, spec, kw))
+            return fn(*args, **kw)
+
+        return wrapped
+
+    for name in ("_conv2d_gemm", "_dense_matmul", "_quant_matmul",
+                 "_fused_elementwise", "_bsr_matmul"):
+        monkeypatch.setattr(kops, name, recorder(getattr(kops, name)))
+    return calls
+
+
+def _frame_plan(app, quant):
+    g = APPS[app](jax.random.PRNGKey(0), base=32)
+    masks, structures = app_masks(g, app, sparsity=0.5)
+    go = PassManager().run(g, PassContext(masks=masks, structures=structures))
+    if not quant:
+        return compile_plan(go, backend="kernel", interpret=False), go.params
+    from repro.quant import calibrate_plan
+
+    c_in = 1 if app == "coloring" else 3
+    calib = [jax.random.normal(jax.random.PRNGKey(1), (2, c_in, 16, 16))]
+    table = calibrate_plan(compile_plan(go, backend="reference"), go.params, calib)
+    gq = PassManager(("quantize",)).run(go, PassContext(
+        calibration=table, quant_skip=APP_QUANT_SKIP[app],
+        act_quant_skip=APP_ACT_SKIP[app],
+    ))
+    return compile_plan(gq, backend="quant", interpret=False), gq.params
+
+
+@pytest.mark.parametrize(
+    "app,size,quant",
+    [(a, s, False) for a, s in _FRAME_CASES] + [("coloring", 512, True)],
+    ids=[a for a, _ in _FRAME_CASES] + ["coloring_int8"],
+)
+def test_frame_plan_kernels_compile(one_chip, monkeypatch, app, size, quant):
+    """Trace the app's plan at batch 4 with the hardware guards armed
+    (``interpret=False``), record every Pallas call it makes -- the convs
+    the VMEM guard admitted at these sizes among them -- and compile each
+    one: any shape the guard admits must compile."""
+    plan, params = _frame_plan(app, quant)
+    calls = _record_kernel_calls(monkeypatch)
+    c_in = 1 if app == "coloring" else 3
+    jax.eval_shape(plan, params, jax.ShapeDtypeStruct((4, c_in, size, size), F32))
+    assert any(fn.__name__ == "conv2d_gemm" for fn, _, _ in calls.values()), app
+    for fn, spec, kw in calls.values():
+        _assert_kernel(one_chip, lambda *a, fn=fn, kw=kw: fn(*a, **kw), *spec)

@@ -236,6 +236,37 @@ def test_ops_filter_restricts_sweeps_but_not_lookups(fresh_cache):
     assert fresh_cache.resolve(*shape, True) == (64, 128, 128, 1)
 
 
+def test_sweep_counts_rejected_candidates(fresh_cache):
+    """A candidate that raises (a tile the compiler refuses) is counted per
+    op and exception type, never mistaken for a slow one."""
+    fresh_cache.enabled = True
+    first = TuningCache.CANDIDATES["matmul"][0]
+
+    def runner(*blocks):
+        if blocks == first:
+            raise ValueError("refused")
+        return jnp.zeros(())
+
+    got = fresh_cache.resolve("matmul", 64, 128, 128, jnp.float32, "dense", True,
+                              runner=runner)
+    assert got != first
+    assert kops.tune_rejected_counts() == {"matmul/ValueError": 1}
+
+
+def test_sweep_raises_when_every_candidate_fails(fresh_cache):
+    fresh_cache.enabled = True
+
+    def runner(*blocks):
+        raise RuntimeError("refused")
+
+    with pytest.raises(RuntimeError, match="every matmul tuning candidate failed"):
+        fresh_cache.resolve("matmul", 64, 128, 128, jnp.float32, "dense", True,
+                            runner=runner)
+    n = len(TuningCache.CANDIDATES["matmul"])
+    assert kops.tune_rejected_counts() == {"matmul/RuntimeError": n}
+    assert not fresh_cache.entries  # no silent default was seeded
+
+
 def test_stats_report_csv_counts_per_family(fresh_cache):
     fresh_cache.resolve("matmul", 8, 8, 8, jnp.float32, "dense", True)   # miss
     fresh_cache.resolve("matmul", 8, 8, 8, jnp.float32, "dense", True)   # hit
