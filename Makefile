@@ -34,7 +34,6 @@ bench-smoke:  ## tiny-shape benchmark pass (CI-sized, no TPU; writes results/BEN
 	python -m benchmarks.table1_apps --smoke
 	python -m benchmarks.serving_bench --smoke
 	python -m benchmarks.robustness_bench --smoke
-	python -m benchmarks.obs_bench --smoke
 	python -m benchmarks.decode_bench --smoke
 	python -m benchmarks.trajectory --check
 

@@ -1,0 +1,21 @@
+"""Mean time, in ms, of the compiled chunk's call (the program's
+``chunk.call`` span: pytree flattening and the enqueue, longer when the
+runtime blocks), over the macro-batches started in the window.  A call
+belongs to the ``batch`` span that holds it, on whichever thread ran it."""
+
+import bisect
+
+
+def read(ctx):
+    t0, t1 = ctx.window
+    batches = sorted((s["ts"], s["ts"] + s["dur"]) for s in ctx.spans
+                     if s["name"] == "batch" and t0 <= s["ts"] < t1)
+    starts = [a for a, _ in batches]
+    durs = []
+    for s in ctx.spans:
+        if s["name"] != "chunk.call":
+            continue
+        i = bisect.bisect_right(starts, s["ts"]) - 1
+        if i >= 0 and s["ts"] + s["dur"] <= batches[i][1]:
+            durs.append(s["dur"])
+    return 1e3 * sum(durs) / len(durs) if durs else None

@@ -157,17 +157,6 @@ FLOORS: dict = {
     ("decode_smoke", "plan:*"): {"require_fusion": True},
     ("decode", "serve"): {"zero_lost": True, "zero_leak": True},
     ("decode_smoke", "serve"): {"zero_lost": True, "zero_leak": True},
-    # observability gates (full + committed smoke reference): telemetry must
-    # stay (nearly) free.  Overheads are vs the bare-loop baseline (see
-    # benchmarks/obs_bench.py): with tracing disabled the instrumented plan
-    # may cost <= 1% extra; with a tracing session armed, the full per-step
-    # span machinery may cost <= 5% end-to-end.
-    ("obs", "overhead:*"): {
-        "max_disabled_overhead": 1.01, "max_traced_overhead": 1.05,
-    },
-    ("obs_smoke", "overhead:*"): {
-        "max_disabled_overhead": 1.01, "max_traced_overhead": 1.05,
-    },
 }
 
 
@@ -220,12 +209,6 @@ def _cases_from(bench: str, rec: dict) -> dict:
         if rcv:
             put("recovery", recovered=rcv["recovered"],
                 breaker_trips=rcv["breaker_trips"])
-    elif bench.startswith("obs"):
-        for r in rec.get("overhead", ()):
-            put(f"overhead:{r['app']}",
-                disabled_overhead=r["disabled_overhead"],
-                traced_overhead=r["traced_overhead"],
-                steps=r["steps"])
     elif bench.startswith("decode"):
         for r in rec.get("parity", ()):
             put(f"parity:{r['case']}", max_err=r["max_err"])
@@ -290,7 +273,7 @@ def collect(results_dir: str = RESULTS_DIR) -> dict:
         if name == "trajectory":
             continue
         if name.endswith("_smoke") and name not in (
-            "serving_smoke", "robustness_smoke", "obs_smoke", "decode_smoke",
+            "serving_smoke", "robustness_smoke", "decode_smoke",
         ):
             continue  # smoke runs are CI plumbing, not perf data
         with open(path) as f:
@@ -402,20 +385,6 @@ def check(traj: dict | None = None, results_dir: str = RESULTS_DIR) -> int:
                         f"{tag}: {fields['watchdog_timeouts']} watchdog "
                         f"timeouts (the ladder, not the watchdog, must "
                         f"absorb overload)"
-                    )
-                d_ovh = fields.get("disabled_overhead")
-                if ("max_disabled_overhead" in floor and d_ovh is not None
-                        and d_ovh > floor["max_disabled_overhead"]):
-                    violations.append(
-                        f"{tag}: disabled-mode overhead {d_ovh:.4f}x > "
-                        f"{floor['max_disabled_overhead']}x"
-                    )
-                t_ovh = fields.get("traced_overhead")
-                if ("max_traced_overhead" in floor and t_ovh is not None
-                        and t_ovh > floor["max_traced_overhead"]):
-                    violations.append(
-                        f"{tag}: traced-mode overhead {t_ovh:.4f}x > "
-                        f"{floor['max_traced_overhead']}x"
                     )
     if violations:
         raise AssertionError(

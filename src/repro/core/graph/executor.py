@@ -842,7 +842,15 @@ class ExecutionPlan:
     ):
         """Execute the plan; ``observer(name, value)`` (if given) sees every
         graph input and node output as it is produced -- the calibration hook
-        used by :func:`repro.quant.calibrate.calibrate_plan`."""
+        used by :func:`repro.quant.calibrate.calibrate_plan`.
+
+        Every step runs under ``jax.named_scope(<node name>)``.  Under
+        tracing, a run on concrete arrays (a direct call, the guarded
+        backend, :func:`repro.obs.profile_plan`) is one ``cat="plan"`` span
+        holding one ``cat="step"`` span per step (op / scheme / backend /
+        output shape; demotions annotated in-span: the ``demoted`` arg and a
+        nested ``cat="guard"`` instant).  A run on tracers, inside
+        :class:`BatchedPlan`'s ``jax.jit``, emits none."""
         if len(args) != len(self.graph.inputs):
             raise TypeError(
                 f"plan expects {len(self.graph.inputs)} inputs "
@@ -853,51 +861,37 @@ class ExecutionPlan:
             for name, v in env.items():
                 observer(name, v)
         guarded = self.backend == "guarded"
-        if _otrace.enabled():  # one branch per run when tracing is off
-            return self._run_steps_traced(env, params, observer, guarded)
-        for step in self.steps:
-            n = step.node
-            xs = [env[i] for i in n.inputs]
-            p = params.get(n.name, {})
-            if guarded:
-                env[n.name] = self._exec_guarded(n, p, xs)
-            else:
-                env[n.name] = self._handlers[n.op](p, xs, n.attrs, self._rt)
-            if observer is not None:
-                observer(n.name, env[n.name])
-            for f in step.frees:  # dead intermediate: release our reference
-                del env[f]
-        outs = tuple(env[o] for o in self.graph.outputs)
-        return outs[0] if len(outs) == 1 else outs
-
-    def _run_steps_traced(self, env, params, observer, guarded):
-        """The traced twin of the ``run_steps`` loop: one ``cat="plan"``
-        span around the run, one ``cat="step"`` span per step carrying op /
-        scheme / backend / output shape, demotions annotated in-span (the
-        ``demoted`` arg + a nested ``cat="guard"`` instant)."""
-        with _otrace.span(
+        # spans time the run only when it runs: under jax.jit this body
+        # runs once per compile, on tracers, and emits none
+        traced = _otrace.enabled() and not any(
+            isinstance(a, jax.core.Tracer) for a in args
+        )
+        run_span = _otrace.span(
             "plan", cat="plan", backend=self.backend, steps=len(self.steps),
             outputs=list(self.graph.outputs),
-        ):
+        ) if traced else _otrace.NULL_SPAN
+        with run_span:
             for step in self.steps:
                 n = step.node
                 xs = [env[i] for i in n.inputs]
                 p = params.get(n.name, {})
-                with _otrace.span(
+                sp = _otrace.span(
                     n.name, cat="step", op=n.op, scheme=_node_scheme(n),
                     backend=self.backend,
-                ) as sp:
+                ) if traced else _otrace.NULL_SPAN
+                # the step's name rides in the op metadata of every
+                # operation it lowers to (the device trace's ops too)
+                with jax.named_scope(n.name), sp:
                     if guarded:
                         y = self._exec_guarded(n, p, xs, sp)
                     else:
                         y = self._handlers[n.op](p, xs, n.attrs, self._rt)
-                    shape = jnp.shape(y)
-                    if all(isinstance(d, int) for d in shape):
-                        sp.set("out_shape", list(shape))
+                    if traced:
+                        sp.set("out_shape", list(jnp.shape(y)))
                 env[n.name] = y
                 if observer is not None:
                     observer(n.name, y)
-                for f in step.frees:
+                for f in step.frees:  # dead intermediate: release our reference
                     del env[f]
         outs = tuple(env[o] for o in self.graph.outputs)
         return outs[0] if len(outs) == 1 else outs
@@ -1191,7 +1185,9 @@ class BatchedPlan:
         most ``batch_size`` (a short chunk is zero-padded to the compiled
         shape and the padding sliced off the outputs).  This is the
         scheduler's entry point -- stats accumulate into ``total_stats``
-        under a lock, so concurrent scheduler threads never corrupt them."""
+        under a lock, so concurrent scheduler threads never corrupt them.
+        Under tracing: ``chunk.pad``, ``chunk.call`` and ``chunk.slice``
+        (``cat="plan"``) time the padding, the compiled call and the slice."""
         b = self._validate(inputs)
         bs = self.batch_size
         if b > bs:
@@ -1201,18 +1197,24 @@ class BatchedPlan:
         xs = inputs
         if b < bs:
             short = bs - b
-            xs = tuple(
-                jnp.concatenate([x, jnp.zeros((short,) + x.shape[1:], x.dtype)])
-                for x in xs
-            )
-        out = self._chunk(params, *xs)
+            with _otrace.span("chunk.pad", "plan"):
+                xs = tuple(
+                    jnp.concatenate(
+                        [x, jnp.zeros((short,) + x.shape[1:], x.dtype)]
+                    )
+                    for x in xs
+                )
+        # returns once the chunk is enqueued, later when the runtime blocks
+        with _otrace.span("chunk.call", "plan"):
+            out = self._chunk(params, *xs)
         with self._lock:
             self.total_stats["frames"] += b
             self.total_stats["batches"] += 1
             self.total_stats["padded_frames"] += bs - b
-        if isinstance(out, tuple):
-            return tuple(o[:b] for o in out)
-        return out[:b]
+        with _otrace.span("chunk.slice", "plan"):
+            if isinstance(out, tuple):
+                return tuple(o[:b] for o in out)
+            return out[:b]
 
     def __call__(self, params: Dict[str, Dict[str, Any]], *inputs):
         b = self._validate(inputs)

@@ -19,6 +19,7 @@ Examples (CPU):
 from __future__ import annotations
 
 import argparse
+import contextlib
 import os
 import threading
 import time
@@ -89,6 +90,26 @@ class _MetricsDump:
               f"{os.path.abspath(self.path)}")
         print(f"trace: {len(buf.events)} events -> {trace_path} "
               f"(load in Perfetto / chrome://tracing)")
+
+
+def _telemetry(args) -> contextlib.ExitStack:
+    """The serving session's telemetry: ``--profile-dir`` runs the JAX
+    profiler over it with tracing armed (the program's spans land in the
+    profiler's trace beside the device's operations); ``--metrics-dump``
+    adds registry snapshots and the buffer's Chrome trace.  Tracing stops
+    before the profiler does, so every span is closed in its trace."""
+    from ..obs import trace
+
+    stack = contextlib.ExitStack()
+    if args.profile_dir:
+        stack.enter_context(
+            jax.profiler.trace(args.profile_dir, create_perfetto_trace=True)
+        )
+    if args.metrics_dump:
+        stack.enter_context(_MetricsDump(args.metrics_dump, args.metrics_interval))
+    elif args.profile_dir:
+        stack.enter_context(trace.tracing())
+    return stack
 
 
 def _pick_backend(args) -> str:
@@ -535,11 +556,18 @@ def main() -> None:
                          "<path>.trace.json (tracing is armed for the run)")
     ap.add_argument("--metrics-interval", type=float, default=0.5,
                     help="seconds between --metrics-dump registry snapshots")
+    ap.add_argument("--profile-dir", default=None,
+                    help="run the JAX profiler over the serving session, "
+                         "tracing armed, and write its trace under this "
+                         "directory: the served spans and the device's "
+                         "operations on one clock (TensorBoard, or "
+                         "perfetto_trace.json.gz in Perfetto)")
     args = ap.parse_args()
     args.backend = _pick_backend(args)
 
-    if args.metrics_dump and (args.async_serve or args.graph_app or args.llm):
-        with _MetricsDump(args.metrics_dump, args.metrics_interval):
+    telemetry = args.metrics_dump or args.profile_dir
+    if telemetry and (args.async_serve or args.graph_app or args.llm):
+        with _telemetry(args):
             if args.async_serve:
                 _serve_async(args)
             elif args.llm:

@@ -7,16 +7,29 @@ point occurrences (guard demotions, watchdog trips), and async events
 (``ph: "b"/"n"/"e"`` with an ``id``) follow a serving request across
 threads from admission to completion.
 
-Overhead contract (gated by ``benchmarks/obs_bench.py``):
+Overhead contract:
 
 * **disabled** (the default): every hook is guarded by the module-level
   :func:`enabled` flag; :func:`span` returns one shared no-op singleton and
   :func:`instant` returns before building anything, so an untraced run
-  allocates nothing and pays one predictable branch per hook (<= 1% on an
-  end-to-end demo-app plan).
+  allocates nothing and pays one predictable branch per hook.  Call sites
+  whose span args cost something to build check :func:`enabled` first.
 * **enabled**: each span appends two small dicts to an in-memory buffer
-  under a lock (<= 5% end to end).  Nothing is serialized until
+  and enters a ``jax.profiler.TraceAnnotation`` of the same name, its
+  scalar args as the annotation's metadata.  Nothing is serialized until
   :meth:`TraceBuffer.chrome_trace` / :meth:`TraceBuffer.save`.
+
+What either mode costs on the served path is measured on the chip, with
+the benchmark's traced and untraced runs of the same seeds: PERF.md
+records the readings.
+
+**Two sinks, one set of spans.**  The buffer is this module's own record,
+on its own clock.  The annotations land in the JAX profiler's trace while
+a profiler session runs (``jax.profiler.start_trace``, or
+``launch/serve.py --profile-dir``): there every span sits on the host
+thread that ran it, on the same clock as the device's operations, so a
+gap in the device's timeline shows what each host thread was doing.
+With no profiler session the annotation records nothing.
 
 The clock is injectable per buffer (``start_tracing(clock=...)``) so tests
 assert exact durations; timestamps are emitted in microseconds, the Chrome
@@ -33,6 +46,8 @@ import os
 import threading
 import time
 from typing import Any, Dict, List, Optional, Tuple
+
+from jax.profiler import TraceAnnotation as _Annotation
 
 __all__ = [
     "TraceBuffer",
@@ -63,10 +78,9 @@ class TraceBuffer:
     """An in-memory list of Chrome-trace events with its own clock.
 
     Recording is lock-free: ``list.append`` is atomic under the GIL, and
-    ``add`` is bound straight to it so the hot path is one C call --
-    the <= 5% traced-mode gate in ``benchmarks/obs_bench.py`` leans on
-    this.  Readers snapshot via ``list(...)`` (also atomic), so
-    cross-thread produce/read interleavings are safe without a mutex."""
+    ``add`` is bound straight to it so the hot path is one C call.
+    Readers snapshot via ``list(...)`` (also atomic), so cross-thread
+    produce/read interleavings are safe without a mutex."""
 
     def __init__(self, clock=time.perf_counter):
         self.clock = clock
@@ -142,13 +156,23 @@ class TraceBuffer:
         ]
 
 
+_SCALARS = (bool, int, float, str)
+
+
+def _scalars(args: Dict[str, Any]) -> Dict[str, Any]:
+    return {k: v for k, v in args.items() if isinstance(v, _SCALARS)}
+
+
 class _Span:
     """A live duration event: B recorded at ``__enter__``, E at
-    ``__exit__``.  ``set`` mutates the B event's args in place (the dict is
-    not serialized until export), so callers can attach results computed
-    mid-span -- output shapes, demotion verdicts -- without a second event."""
+    ``__exit__``, and a profiler annotation of the same name open between
+    them.  ``set`` mutates the B event's args in place (the dict is not
+    serialized until export) and adds a scalar value to the annotation's
+    metadata, so callers can attach results computed mid-span -- output
+    shapes, demotion verdicts, an admitted request's id -- without a
+    second event."""
 
-    __slots__ = ("_buf", "_begin")
+    __slots__ = ("_buf", "_begin", "_ann")
 
     def __init__(self, buf: TraceBuffer, name: str, cat: str,
                  args: Dict[str, Any]):
@@ -157,24 +181,31 @@ class _Span:
             "name": name, "cat": cat, "ph": "B", "pid": buf.pid,
             "tid": _get_ident(), "ts": buf.clock() * 1e6, "args": args,
         }
+        self._ann = None
 
     def __enter__(self) -> "_Span":
-        self._buf.add(self._begin)
+        b = self._begin
+        self._ann = _Annotation(b["name"], **_scalars(b["args"]))
+        self._ann.__enter__()
+        self._buf.add(b)
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
         b = self._begin
         if exc_type is not None:
-            b["args"]["error"] = exc_type.__name__
+            self.set("error", exc_type.__name__)
         buf = self._buf
         buf.add({
             "name": b["name"], "cat": b["cat"], "ph": "E", "pid": b["pid"],
             "tid": b["tid"], "ts": buf.clock() * 1e6,
         })
+        self._ann.__exit__(None, None, None)
         return False
 
     def set(self, key: str, value: Any) -> None:
         self._begin["args"][key] = value
+        if self._ann is not None and isinstance(value, _SCALARS):
+            self._ann.set_metadata(**{key: value})
 
 
 class _NullSpan:

@@ -719,8 +719,23 @@ class AsyncPlanServer:
         (:class:`QuotaExceededError`), its ladder rung may shed a
         low-priority request outright (:class:`LadderShedError`) or route
         it to the plan's registered cheaper variant, and its weight sets
-        the fair share of batch slots the request competes under."""
-        with self._lock:
+        the fair share of batch slots the request competes under.
+
+        Under tracing the whole call is one ``submit`` span (``rid`` set
+        once admitted) holding ``submit.lock`` (waiting for the server's
+        lock) and ``submit.to_device`` (the frame's copy to the device)."""
+        with _otrace.span("submit", "serving") as sp:
+            return self._submit(
+                sp, plan_name, frame_inputs, priority, deadline, tenant
+            )
+
+    def _submit(
+        self, sp, plan_name: str, frame_inputs, priority: int,
+        deadline: Optional[float], tenant: Optional[str],
+    ) -> RequestHandle:
+        with _otrace.span("submit.lock", "serving"):
+            self._lock.acquire()
+        try:
             if self.closed:
                 raise RuntimeError("AsyncPlanServer is closed; no further requests")
             entry = self._plans.get(plan_name)
@@ -741,7 +756,8 @@ class AsyncPlanServer:
                     f"plan {plan_name!r} expects {n_in} inputs per frame, "
                     f"got {len(frame_inputs)}"
                 )
-            frames = tuple(jnp.asarray(f) for f in frame_inputs)
+            with _otrace.span("submit.to_device", "serving"):
+                frames = tuple(jnp.asarray(f) for f in frame_inputs)
             # shape/dtype gate: one malformed request fails HERE (its own
             # "handle"), never inside the macro-batch it would have joined
             if entry.input_spec is None:
@@ -824,6 +840,7 @@ class AsyncPlanServer:
                 submitted_at=now,
             )
             self._rid += 1
+            sp.set("rid", handle.rid)
             handle._inputs = frames
             handle._seq = entry.seq
             entry.seq += 1
@@ -843,6 +860,8 @@ class AsyncPlanServer:
                     "request", handle.rid, cat="serving", plan=plan_name,
                     priority=priority, tenant=t.name,
                 )
+        finally:
+            self._lock.release()
         if shed is not None:
             shed._fail(
                 QueueFullError(
@@ -1072,19 +1091,23 @@ class AsyncPlanServer:
         harmless) -- a hung kernel costs one batch, never the scheduler.
 
         Under tracing the whole call is one ``cat="serving"`` batch span
-        (carrying the served rids and release ``reason``); each member
-        request gets a ``batched`` milestone naming this batch and its
-        terminal ``e`` event at the verdict."""
+        (carrying the served rids and release ``reason``) holding
+        ``batch.stack`` (the frames stacked on the device), the chunk's own
+        ``chunk.*`` spans and ``batch.resolve`` (``batch.lock``, then every
+        handle's slice and verdict); each member request gets a ``batched``
+        milestone naming this batch and its terminal ``e`` event at the
+        verdict."""
         box: Dict[str, Any] = {}
 
         def compute() -> None:
             try:
                 # stacking stays inside the guard: a failing frame must fail
                 # its batch's handles, never kill the scheduler thread
-                inputs = tuple(
-                    jnp.stack([h._inputs[i] for h in batch])
-                    for i in range(len(batch[0]._inputs))
-                )
+                with _otrace.span("batch.stack", "serving"):
+                    inputs = tuple(
+                        jnp.stack([h._inputs[i] for h in batch])
+                        for i in range(len(batch[0]._inputs))
+                    )
                 box["out"] = runner.batched.run_chunk(runner.params, *inputs)
             except Exception as e:  # resolve handles; callers see the error
                 box["err"] = e
@@ -1092,11 +1115,13 @@ class AsyncPlanServer:
         with self._lock:
             bid = self._batch_seq
             self._batch_seq += 1
-        with _otrace.span(
+        traced = _otrace.enabled()
+        bsp = _otrace.span(
             "batch", cat="serving", plan=entry.name, batch=bid, reason=reason,
             version=runner.label(), rids=[h.rid for h in batch],
-        ) as bsp:
-            if _otrace.enabled():
+        ) if traced else _otrace.NULL_SPAN
+        with bsp:
+            if traced:
                 for h in batch:
                     _otrace.async_instant(
                         "request", h.rid, cat="serving", phase="batched",
@@ -1113,71 +1138,75 @@ class AsyncPlanServer:
                 worker.join(self.watchdog)
                 timed_out = worker.is_alive()
             now = self._clock()
-            with self._lock:
-                out = box.get("out")
-                err = box.get("err")
-                if timed_out:
-                    out = None
-                    err = WatchdogTimeout(
-                        f"batch of {len(batch)} on plan {entry.name!r} "
-                        f"exceeded the {self.watchdog}s watchdog deadline"
-                    )
-                    self._bump(entry, "watchdog_timeouts")
-                    bsp.set("timed_out", True)
-                    _otrace.instant(
-                        "watchdog_timeout", cat="serving", plan=entry.name,
-                        batch=bid,
-                    )
-                traced = _otrace.enabled()
-                for i, h in enumerate(batch):
-                    h._inputs = None  # executed: release the frame arrays
-                    if err is not None:
-                        h._fail(err, now)
-                    else:
-                        h._resolve(
-                            tuple(o[i] for o in out) if isinstance(out, tuple)
-                            else out[i],
-                            now,
+            with _otrace.span("batch.resolve", "serving"):
+                with _otrace.span("batch.lock", "serving"):
+                    self._lock.acquire()
+                try:
+                    out = box.get("out")
+                    err = box.get("err")
+                    if timed_out:
+                        out = None
+                        err = WatchdogTimeout(
+                            f"batch of {len(batch)} on plan {entry.name!r} "
+                            f"exceeded the {self.watchdog}s watchdog deadline"
                         )
-                    t = self._tenants.get(h.tenant)
-                    if h.deadline_missed:
-                        self._bump(entry, "deadline_misses")
-                        if t is not None:
-                            self._bump_tenant(t, "deadline_misses")
+                        self._bump(entry, "watchdog_timeouts")
+                        bsp.set("timed_out", True)
                         _otrace.instant(
-                            "deadline_miss", cat="serving", plan=entry.name,
-                            rid=h.rid, batch=bid,
+                            "watchdog_timeout", cat="serving", plan=entry.name,
+                            batch=bid,
                         )
-                    self._bump(entry, "completed")
-                    if t is not None:
-                        self._bump_tenant(t, "completed")
-                    if h.latency is not None:
-                        entry.latencies.append(h.latency)
-                        _metrics.registry().histogram(
-                            "serving_latency_seconds", plan=entry.name
-                        ).observe(h.latency)
+                    for i, h in enumerate(batch):
+                        h._inputs = None  # executed: release the frame arrays
+                        if err is not None:
+                            h._fail(err, now)
+                        else:
+                            h._resolve(
+                                tuple(o[i] for o in out) if isinstance(out, tuple)
+                                else out[i],
+                                now,
+                            )
+                        t = self._tenants.get(h.tenant)
+                        if h.deadline_missed:
+                            self._bump(entry, "deadline_misses")
+                            if t is not None:
+                                self._bump_tenant(t, "deadline_misses")
+                            _otrace.instant(
+                                "deadline_miss", cat="serving", plan=entry.name,
+                                rid=h.rid, batch=bid,
+                            )
+                        self._bump(entry, "completed")
                         if t is not None:
-                            t.observe(h.latency, h.deadline_missed)
+                            self._bump_tenant(t, "completed")
+                        if h.latency is not None:
+                            entry.latencies.append(h.latency)
                             _metrics.registry().histogram(
-                                "serving_tenant_latency_seconds",
-                                tenant=t.name,
+                                "serving_latency_seconds", plan=entry.name
                             ).observe(h.latency)
-                    self._completed.append(h)
-                    if traced:
-                        _otrace.async_end(
-                            "request", h.rid, cat="serving",
-                            phase="failed" if err is not None else "completed",
-                            batch=bid, deadline_missed=h.deadline_missed,
-                        )
-                self._bump(entry, "batches")
-                self._bump(
-                    entry, "padded_frames",
-                    runner.batch_size - len(batch),
-                )
-                runner.outstanding -= len(batch)
-                self._maybe_retire(entry)
-                self._inflight -= 1
-                self._idle.notify_all()
+                            if t is not None:
+                                t.observe(h.latency, h.deadline_missed)
+                                _metrics.registry().histogram(
+                                    "serving_tenant_latency_seconds",
+                                    tenant=t.name,
+                                ).observe(h.latency)
+                        self._completed.append(h)
+                        if traced:
+                            _otrace.async_end(
+                                "request", h.rid, cat="serving",
+                                phase="failed" if err is not None else "completed",
+                                batch=bid, deadline_missed=h.deadline_missed,
+                            )
+                    self._bump(entry, "batches")
+                    self._bump(
+                        entry, "padded_frames",
+                        runner.batch_size - len(batch),
+                    )
+                    runner.outstanding -= len(batch)
+                    self._maybe_retire(entry)
+                    self._inflight -= 1
+                    self._idle.notify_all()
+                finally:
+                    self._lock.release()
 
     def step(self, *, force: bool = False) -> int:
         """One synchronous scheduler tick: visit every plan queue in fair
@@ -1492,8 +1521,9 @@ class AsyncPlanServer:
                 with self._lock:
                     self._tick_errors += 1
                 executed = 0
-            if executed == 0:
-                self._work.wait(self.tick_interval)
+            if executed == 0:  # nothing was ready by the policy
+                with _otrace.span("server.wait", "serving"):
+                    self._work.wait(self.tick_interval)
                 self._work.clear()
 
     # -- completion / teardown ----------------------------------------------- #

@@ -6,7 +6,10 @@ through the executor / pass manager / serving scheduler -- per-step spans
 match plan step count for every demo app, and a serving trace links every
 completed request to exactly one macro-batch span."""
 
+import contextlib
+import glob
 import json
+import os
 import threading
 
 import jax
@@ -276,6 +279,26 @@ def test_async_events_cross_thread_ids():
 # --------------------------------------------------------------------------- #
 
 
+def test_jitted_plan_emits_no_plan_or_step_spans():
+    go, plan = _plan("coloring")
+    x = _frame("coloring")[None]
+    with trace.tracing() as buf:
+        jax.jit(plan)(go.params, x)  # run_steps runs on tracers, once
+        assert not buf.spans()
+        plan(go.params, x)  # the eager run still times its steps
+    assert len([s for s in buf.spans() if s["cat"] == "step"]) == len(plan.steps)
+
+
+def test_plan_steps_name_the_ops_they_lower_to():
+    go, plan = _plan("coloring")
+    hlo = jax.jit(plan).lower(go.params, _frame("coloring")[None]).compile().as_text()
+    scopes = {
+        st.node.name for st in plan.steps if f"/{st.node.name}/" in hlo
+    }
+    convs = {st.node.name for st in plan.steps if st.node.op == "conv2d"}
+    assert convs and convs <= scopes
+
+
 @pytest.mark.parametrize("app", sorted(APPS))
 def test_per_step_spans_match_plan_step_count(app):
     go, plan = _plan(app)
@@ -483,3 +506,163 @@ def test_shed_request_ends_its_trace_span():
         server.step(force=True)
         server.close()
     assert h2.done()
+
+
+# --------------------------------------------------------------------------- #
+# the served host path: submit, batch, chunk                                   #
+# --------------------------------------------------------------------------- #
+
+
+def _ticks():
+    t = [0.0]
+
+    def clock():
+        t[0] += 1e-6  # 1us per read: every span has a length, none ties
+        return t[0]
+
+    return clock
+
+
+def _inside(outer, spans):
+    """The spans on ``outer``'s thread that lie strictly inside it."""
+    end = outer["ts"] + outer["dur"]
+    return [
+        s for s in spans
+        if s is not outer and s["tid"] == outer["tid"]
+        and outer["ts"] < s["ts"] and s["ts"] + s["dur"] < end
+    ]
+
+
+def _children(outer, spans):
+    """The spans directly inside ``outer``, in start order."""
+    inner = _inside(outer, spans)
+    return [s for s in inner if not any(s in _inside(o, inner) for o in inner)]
+
+
+def _served(n, **kw):
+    """``n`` frames submitted, then served by synchronous ticks (the last,
+    partial batch forced) under a tracing session on an injected clock."""
+    server = _sr_server(**kw)
+    with trace.tracing(_ticks()) as buf:
+        handles = [
+            server.submit("sr", _frame("super_resolution", i)) for i in range(n)
+        ]
+        while server.step():
+            pass
+        server.step(force=True)
+        server.close()
+    assert all(h.done() for h in handles)
+    return handles, buf.spans()
+
+
+def test_batch_spans_hold_one_chunk_call_between_stack_and_resolve():
+    _, spans = _served(5)  # batch 2: two full batches and one padded
+    batches = [s for s in spans if s["name"] == "batch"]
+    assert [len(b["args"]["rids"]) for b in batches] == [2, 2, 1]
+    for b in batches:
+        kids = [s["name"] for s in _children(b, spans)]
+        padded = len(b["args"]["rids"]) < 2
+        assert kids == (
+            ["batch.stack"] + ["chunk.pad"] * padded
+            + ["chunk.call", "chunk.slice", "batch.resolve"]
+        )
+        assert [s["name"] for s in _inside(b, spans)].count("chunk.call") == 1
+        (resolve,) = [s for s in _children(b, spans) if s["name"] == "batch.resolve"]
+        assert [s["name"] for s in _children(resolve, spans)] == ["batch.lock"]
+    # the chunk is jitted: its run on tracers emitted no plan/step spans
+    assert not [s for s in spans if s["name"] == "plan" or s["cat"] == "step"]
+
+
+def test_submit_spans_hold_lock_and_copy_and_carry_the_rid():
+    handles, spans = _served(3)
+    submits = [s for s in spans if s["name"] == "submit"]
+    assert [s["args"]["rid"] for s in submits] == [h.rid for h in handles]
+    for s in submits:
+        assert s["cat"] == "serving"
+        assert [c["name"] for c in _children(s, spans)] == [
+            "submit.lock", "submit.to_device",
+        ]
+
+
+def test_disabled_serving_path_builds_no_span_and_no_args(monkeypatch):
+    assert not trace.enabled()
+    server = _sr_server()
+    calls = []
+    real = trace.span
+
+    def spy(name, cat="repro", **args):
+        calls.append((name, args))
+        return real(name, cat, **args)
+
+    def refuse(*a, **k):
+        raise AssertionError("a live span was built with tracing off")
+
+    monkeypatch.setattr(trace, "span", spy)
+    monkeypatch.setattr(trace._Span, "__init__", refuse)
+    handles = [server.submit("sr", _frame("super_resolution", i)) for i in range(3)]
+    while server.step():
+        pass
+    server.step(force=True)
+    server.close()
+    assert all(h.done() for h in handles)
+    names = {n for n, _ in calls}
+    assert {
+        "submit", "submit.lock", "submit.to_device", "batch.stack",
+        "chunk.pad", "chunk.call", "chunk.slice", "batch.resolve", "batch.lock",
+    } <= names
+    assert "batch" not in names  # its rids list is never built
+    assert all(args == {} for _, args in calls)
+    assert trace.current_buffer() is None
+
+
+def _profiled(how, logdir):
+    """A profiler session over a traced block: opened by hand, or as
+    ``launch/serve.py --profile-dir`` opens it."""
+    if how == "start_trace":
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        stack = contextlib.ExitStack()
+        stack.enter_context(jax.profiler.trace(logdir, profiler_options=opts))
+        stack.enter_context(trace.tracing())
+        return stack
+    from types import SimpleNamespace
+
+    from repro.launch.serve import _telemetry
+
+    return _telemetry(SimpleNamespace(
+        profile_dir=logdir, metrics_dump=None, metrics_interval=0.5,
+    ))
+
+
+@pytest.mark.parametrize("how", ["start_trace", "serve_profile_dir"])
+def test_spans_land_in_the_profiler_trace_on_a_host_plane(tmp_path, how):
+    from jax.profiler import ProfileData
+
+    server = _sr_server()
+    warm = [server.submit("sr", _frame("super_resolution", i)) for i in range(2)]
+    server.step()  # compile outside the profiled session
+    assert all(h.done() for h in warm)
+    with _profiled(how, str(tmp_path)):
+        assert trace.enabled()
+        hs = [server.submit("sr", _frame("super_resolution", i)) for i in range(2)]
+        server.step()
+    assert not trace.enabled()
+    server.close()
+    run = os.path.join(str(tmp_path), "plugins", "profile", "*")
+    (path,) = glob.glob(os.path.join(run, "*.xplane.pb"))
+    if how == "serve_profile_dir":  # and a file Perfetto opens
+        assert glob.glob(os.path.join(run, "perfetto_trace.json.gz"))
+    found = {}
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name in ("batch", "submit", "chunk.call"):
+                    stats = {k: v for k, v in ev.stats}
+                    found.setdefault(ev.name, []).append((plane.name, stats))
+    (plane, stats), = found["batch"]
+    assert plane.startswith("/host:")
+    assert stats["plan"] == "sr" and stats["reason"] == "full"
+    assert stats["batch"] == 1  # the second batch this server ran
+    assert "rids" not in stats  # a list: the buffer keeps it, not the annotation
+    assert sorted(st["rid"] for _, st in found["submit"]) == [h.rid for h in hs]
+    assert len(found["chunk.call"]) == 1
