@@ -28,6 +28,7 @@ from .passes import (
     dce,
     fold_gathers,
     fold_norm,
+    fold_upsample_conv,
     fuse_activation,
     fuse_elementwise,
     fuse_epilogue,

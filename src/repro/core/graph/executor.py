@@ -497,9 +497,14 @@ def _norm(p, xs, a, rt):
             "bias"
         ][None, :, None, None]
     if kind == "instance":  # per (N, C) over spatial
-        mu = x.mean(axis=(2, 3), keepdims=True)
-        var = x.var(axis=(2, 3), keepdims=True)
-        y = (x - mu) / jnp.sqrt(var + eps)
+        # ``phases`` consecutive channels share statistics: the phases of
+        # one full-resolution channel ahead of a pixel shuffle
+        n, c, h, w = x.shape
+        ph = a.get("phases", 1)
+        xg = x.reshape(n, c // ph, ph, h, w)
+        mu = xg.mean(axis=(2, 3, 4), keepdims=True)
+        var = xg.var(axis=(2, 3, 4), keepdims=True)
+        y = ((xg - mu) / jnp.sqrt(var + eps)).reshape(x.shape)
         return y * p["scale"][None, :, None, None] + p["bias"][None, :, None, None]
     if kind == "layer":  # over last dim
         mu = x.mean(axis=-1, keepdims=True)

@@ -20,7 +20,8 @@ conv2d             params w[Co,Ci,kh,kw], b?, kept? (channelcompact: live
                    input-channel indices, Ci already compacted); attrs
                    stride, padding, groups, dilation, format?,
                    activation?, epilogue?
-norm               attrs kind in {batch, instance, layer}; params
+norm               attrs kind in {batch, instance, layer}, phases?
+                   (instance: channels sharing statistics); params
                    scale, bias (+ mean, var for batch)
 activation         attrs fn
 add / mul          (binary, elementwise)

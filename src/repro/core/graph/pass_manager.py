@@ -215,6 +215,7 @@ DEFAULT_PIPELINE: Tuple[str, ...] = (
     "fuse_activation",
     "substitute_sparse",
     "fold_gathers",
+    "fold_upsample_conv",
     "cse",
     "fuse_elementwise",
     "fuse_epilogue",

@@ -8,7 +8,9 @@ Pass pipeline for deployment (see :func:`optimize`):
 3. ``substitute_sparse`` pruned weights -> compact formats + sparse ops
                          (ColumnCompact / ChannelCompact / PBCSR+reorder)
 4. ``fold_gathers``      compaction gathers folded into adjacent weights
-5. ``dce``               drop dead nodes
+5. ``fold_upsample_conv`` nearest-2x upsample + 3x3 conv -> low-resolution
+                         phase conv + pixel shuffle
+6. ``dce``               drop dead nodes
 
 All passes are pure: Graph in, Graph out.
 """
@@ -21,6 +23,7 @@ from typing import Any, Dict, List, Optional, Tuple
 import jax.numpy as jnp
 import numpy as np
 
+from ...obs import metrics as _metrics
 from ..pruning.structures import Block, Channel, Column, PatternKernel, Structure
 from ..sparse.formats import ChannelCompact, ColumnCompact, PBCSR
 from ..sparse.packing import block_mask
@@ -32,6 +35,7 @@ __all__ = [
     "fuse_activation",
     "substitute_sparse",
     "fold_gathers",
+    "fold_upsample_conv",
     "fuse_elementwise",
     "fuse_epilogue",
     "quantize",
@@ -324,6 +328,116 @@ def fold_gathers(g: Graph) -> Graph:
 
 
 # --------------------------------------------------------------------------- #
+# 4b. upsample-conv folding                                                    #
+# --------------------------------------------------------------------------- #
+
+#: ``_PHASE_TAPS[a, r, k]`` is 1 where tap ``k`` of a 3x3 SAME conv over a
+#: nearest-2x upsampled axis reads low-resolution row offset ``r - 1`` at
+#: output phase ``a``: ``r - 1 == (a + k - 1) // 2``.
+_PHASE_TAPS = np.array(
+    [[[float((a + k - 1) // 2 == r - 1) for k in range(3)] for r in range(3)]
+     for a in range(2)],
+    np.float32,
+)
+
+
+def _repeat_phases(v):
+    """A per-channel vector for the 4 phase channels of each channel
+    (on the host: the plan is built once, so no device op is compiled)."""
+    v_np = np.asarray(v)
+    return jnp.asarray(np.repeat(v_np, 4), v_np.dtype)
+
+
+def _foldable_conv(g: Graph, up: Node) -> Optional[Node]:
+    """The conv the fold applies to: the only consumer of a nearest-2x
+    ``up`` that is no graph output, a stride-1 SAME 3x3 conv reading ``up``
+    alone, with no groups, dilation or epilogue.  Else None."""
+    cons = g.consumers(up.name)
+    if up.attrs.get("factor") != 2 or up.name in g.outputs or len(cons) != 1:
+        return None
+    conv, a = cons[0], cons[0].attrs
+    if (
+        conv.op == "conv2d"
+        and conv.inputs == (up.name,)
+        and tuple(g.params[conv.name]["w"].shape[2:]) == (3, 3)
+        and a.get("stride", 1) == 1
+        and a.get("padding", "SAME") == "SAME"
+        and a.get("groups", 1) == 1
+        and a.get("dilation", 1) == 1
+        and not a.get("epilogue")
+    ):
+        return conv
+    return None
+
+
+def fold_upsample_conv(g: Graph) -> Graph:
+    """Fold a nearest-2x ``upsample`` into the 3x3 SAME conv that is its only
+    consumer: the sub-pixel identity.
+
+    conv3x3(upsample2x(x)) == pixel_shuffle(conv3x3'(x), 2), where conv'
+    runs at the *low* resolution with 4x the output channels: channel
+    ``4*o + 2*a + b`` holds phase ``(a, b)`` of output channel ``o``, and its
+    tap ``(ry+1, rx+1)`` is the float32 sum of the original taps ``(ky, kx)``
+    with ``ry = (a+ky-1)//2``, ``rx = (b+kx-1)//2`` (the bias repeats per
+    phase).  The multiply-adds are the original count (structural zeros
+    in the 3x3 phase taps); the 4x upsampled tensor is never built.
+
+    The conv keeps its name, ``kept``, ``format`` and fused ``activation``.
+    A straight chain of instance norms and activations after it moves ahead
+    of the shuffle too, so it runs on the phase channels: each norm takes
+    ``phases=4`` (statistics over a channel's four phases, i.e. the same
+    full-resolution plane) and its scale/bias repeat per phase.  A
+    ``pixel_shuffle`` node ``<conv>_shuffle`` then takes over the chain's
+    consumers.  Each fold counts in
+    ``graph_rewrites_total{pass="fold_upsample_conv"}``.
+    """
+    for name in [n.name for n in g.nodes if n.op == "upsample"]:
+        up = g.node(name)  # an earlier fold may have rewired its input
+        conv = _foldable_conv(g, up)
+        if conv is None:
+            continue
+        p = g.params[conv.name]
+        w = np.asarray(p["w"], np.float32)
+        wp = np.einsum("ark,bsl,ockl->oabcrs", _PHASE_TAPS, _PHASE_TAPS, w)
+        wp = wp.reshape(4 * w.shape[0], w.shape[1], 3, 3)
+        new_p = {**p, "w": jnp.asarray(wp, p["w"].dtype)}
+        if p.get("b") is not None:
+            new_p["b"] = _repeat_phases(p["b"])
+        g = g.without({up.name}).replace_node(
+            conv.name, conv.replace(inputs=up.inputs)
+        )
+        g.params[conv.name] = new_p
+        tail = conv.name
+        while tail not in g.outputs and len(g.consumers(tail)) == 1:
+            nxt = g.consumers(tail)[0]
+            if nxt.op == "norm" and nxt.attrs.get("kind") == "instance" and (
+                nxt.attrs.get("phases", 1) == 1
+            ):
+                g = g.replace_node(
+                    nxt.name, nxt.replace(attrs={**nxt.attrs, "phases": 4})
+                )
+                g.params[nxt.name] = {
+                    k: _repeat_phases(v) for k, v in g.params[nxt.name].items()
+                }
+            elif nxt.op != "activation":
+                break
+            tail = nxt.name
+        names = {n.name for n in g.nodes}
+        shuffle_name = conv.name + "_shuffle"
+        while shuffle_name in names:
+            shuffle_name += "_"
+        g = _insert_after(g, tail, Node(
+            op="pixel_shuffle", name=shuffle_name, inputs=(tail,),
+            attrs={"factor": 2},
+        ))
+        _metrics.registry().counter(
+            "graph_rewrites_total", **{"pass": "fold_upsample_conv"}
+        ).inc()
+    g.validate()
+    return g
+
+
+# --------------------------------------------------------------------------- #
 # 5. elementwise-chain fusion                                                  #
 # --------------------------------------------------------------------------- #
 
@@ -466,7 +580,10 @@ def _epilogue_candidate(g: Graph, n: Node):
             return None
         side = b_name if src == a_name else a_name
         return src, [(n.op, ("side", side))]
-    if n.op == "norm" and n.attrs.get("kind") in ("instance", "layer"):
+    if (
+        n.op == "norm" and n.attrs.get("kind") in ("instance", "layer")
+        and n.attrs.get("phases", 1) == 1  # the epilogue's norms are per channel
+    ):
         p = g.params.get(n.name, {})
         kind = "norm_instance" if n.attrs["kind"] == "instance" else "norm_layer"
         return n.inputs[0], [
@@ -800,6 +917,9 @@ register_pass("substitute_sparse", needs_masks=True, post=(params_bound_to_nodes
 )
 register_pass("fold_gathers", needs_masks=True, post=(params_bound_to_nodes,))(
     lambda g, ctx: fold_gathers(g)
+)
+register_pass("fold_upsample_conv", post=(params_bound_to_nodes,))(
+    lambda g, ctx: fold_upsample_conv(g)
 )
 register_pass("cse", post=(params_bound_to_nodes,))(lambda g, ctx: cse(g))
 register_pass("fuse_elementwise", post=(params_bound_to_nodes,))(

@@ -253,7 +253,12 @@ def test_frame_plan_kernels_compile(one_chip, monkeypatch, app, size, quant):
     plan, params = _frame_plan(app, quant)
     calls = _record_kernel_calls(monkeypatch)
     c_in = 1 if app == "coloring" else 3
+    kops.reset_conv_fallbacks()
     jax.eval_shape(plan, params, jax.ShapeDtypeStruct((4, c_in, size, size), F32))
     assert any(fn.__name__ == "conv2d_gemm" for fn, _, _ in calls.values()), app
+    if app == "style_transfer":
+        # conv_in, down0 and conv_out stay whole-frame lax.conv at 512^2;
+        # up1 runs as a 256^2 phase conv (fold_upsample_conv) the guard admits
+        assert kops.conv_fallback_counts() == {"vmem": 3}
     for fn, spec, kw in calls.values():
         _assert_kernel(one_chip, lambda *a, fn=fn, kw=kw: fn(*a, **kw), *spec)
